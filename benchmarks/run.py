@@ -127,6 +127,8 @@ def main() -> None:
     if "--all" in sys.argv[1:]:
         run_all(os.path.dirname(os.path.abspath(__file__)))
         return
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_engine_speedup, bench_gas,
                             bench_l1_throughput, bench_l2_throughput,
                             bench_latency, bench_protocol, bench_prover,

@@ -20,6 +20,7 @@ from repro.fl.client import ClientConfig, TrainingAgent
 from repro.fl.dp import DPConfig
 from repro.fl.partition import dirichlet_partition, skew_report
 from repro.fl.server import AutoDFL
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lenet
 from repro.models.model import build_model
 from repro.optim.optimizers import OptimizerSpec, make_optimizer
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--no-rollup", action="store_true",
                     help="single-layer L1 baseline (paper Fig. 5 comparison)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config("lenet5")
     model = build_model(cfg)

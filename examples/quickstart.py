@@ -18,6 +18,7 @@ import numpy as np
 from repro.api import NodeClient, NodeSpec, ShardSpec
 from repro.configs.registry import REGISTRY, reduced_config
 from repro.fl.round import FLRoundSpec, build_fl_round
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.optim.optimizers import OptimizerSpec, make_optimizer
 
@@ -53,6 +54,7 @@ def main():
     ap.add_argument("--arch", default="qwen2-0.5b", choices=sorted(REGISTRY))
     ap.add_argument("--steps", type=int, default=3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     api_demo()
 

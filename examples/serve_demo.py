@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.configs.registry import REGISTRY, reduced_config
 from repro.core.reputation import ReputationParams, init_book
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 
 
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=12)
     ap.add_argument("--tokens", type=int, default=12)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced_config(REGISTRY[args.arch])
     assert cfg.input_mode == "tokens" and not cfg.enc_dec, \

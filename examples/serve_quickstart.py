@@ -12,6 +12,7 @@ Usage:
 import asyncio
 
 from repro.api import AdmissionSpec, NodeSpec, ServeSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import HttpNodeServer, NodeService, http_rpc
 
 
@@ -72,4 +73,5 @@ async def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     asyncio.run(main())

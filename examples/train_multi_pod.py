@@ -10,8 +10,10 @@ Usage:
         --arch qwen2-0.5b --rounds 3 --local-steps 2 --host-mesh --reduced
 """
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import main as train_main
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     train_main()

@@ -24,9 +24,9 @@ STATE_COLUMNS: Tuple[str, ...] = (
 )
 
 #: kernel-registry contract: the NumPy mirror is semantics-of-record and
-#: every op must carry at least these impl families (R002).
+#: every op must carry it plus at least one device impl (R002).
 REQUIRED_MIRROR_IMPL = "numpy"
-MIN_IMPLS_PER_OP = 3
+MIN_IMPLS_PER_OP = 2
 
 #: determinism sweep seeds (R003): classes whose methods anchor the
 #: reachability walk, plus the free functions on the digest path.
@@ -74,14 +74,14 @@ CATALOG: Dict[str, Invariant] = {inv.rule: inv for inv in (
     ),
     Invariant(
         rule="R002",
-        title="kernel registry ops carry numpy mirror + >=3 impls + parity test",
+        title="kernel registry ops carry numpy mirror + a device impl + parity test",
         rationale=(
             "kernels/factory.py's contract is that the NumPy mirror is the "
             "semantics-of-record and jax/pallas/shard_map impls are pinned "
             "bit-exact against it by a tests/test_kernels.py-family test; "
             "an op missing an impl or a parity pin can drift per backend."),
         fix_hint=(
-            "register a 'numpy' mirror plus at least two device impls for "
+            "register a 'numpy' mirror plus at least one device impl for "
             "the op, and add a parity test mentioning the op name under "
             "tests/"),
         static=True, dynamic=False,

@@ -307,7 +307,8 @@ def check_r002(mods: Sequence[Module]) -> List[Finding]:
             findings.append(Finding(
                 mod.path, line, col, "R002",
                 f"kernel op {op!r} registers only {sorted(impls)}; the "
-                f"factory contract is >= {MIN_IMPLS_PER_OP} impls per op",
+                f"factory contract is the {REQUIRED_MIRROR_IMPL!r} mirror "
+                f"plus a device impl (>= {MIN_IMPLS_PER_OP} impls per op)",
                 fix_hint("R002")))
         root = _repo_root_of(mod.path)
         if root is not None and op not in _test_corpus(root):

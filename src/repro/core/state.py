@@ -160,21 +160,19 @@ _ON_TPU: Optional[bool] = None
 
 
 def tpu_digest_backend() -> bool:
-    """Whether the "auto" digest backend should route through Pallas.
+    """Whether the "auto" kernel choice should take the TPU defaults.
 
     Probed ONCE per process: ``jax.default_backend()`` costs ~2ms per
     call, which dominated every ``state_root()``/seal digest on the hot
     path when probed inline (roots are per-window now — see
     prover.ProverFace._emit_window).  The device set cannot change
-    mid-process, so caching is safe.
+    mid-process, so caching is safe.  A failing probe raises: answering
+    "not a TPU" would quietly run the CPU mirrors on the chip.
     """
     global _ON_TPU
     if _ON_TPU is None:
-        try:
-            import jax
-            _ON_TPU = jax.default_backend() == "tpu"
-        except Exception:  # pragma: no cover - jax is always in-tree
-            _ON_TPU = False
+        import jax
+        _ON_TPU = jax.default_backend() == "tpu"
     return _ON_TPU
 
 
@@ -199,16 +197,20 @@ def chunk_fold_digests(words: np.ndarray,
 def _fold_digests(words: np.ndarray, chunk: int,
                   backend: str) -> np.ndarray:
     """Full per-chunk digest vector, routed by ``backend`` ("numpy" forces
-    the mirror, "pallas" forces the kernel, "auto" probes the device)."""
-    if backend != "numpy":
-        use_pallas = backend == "pallas" or (backend == "auto"
-                                             and tpu_digest_backend())
-        if use_pallas and len(words):
-            import jax.numpy as jnp
-            from repro.kernels.rollup_digest import rollup_chunk_digests
-            return np.asarray(rollup_chunk_digests(
-                jnp.asarray(np.ascontiguousarray(words, np.uint32)),
-                chunk_p=chunk))
+    the mirror, "pallas" forces the kernel).  "auto" follows the kernel
+    factory's choice for ``dirty_fold`` — the incremental half of the
+    same commitment — so both halves run on the same side."""
+    if backend == "auto":
+        from repro.kernels.factory import resolve_impl
+        backend = resolve_impl("dirty_fold")
+    if backend == "pallas" and len(words):
+        import jax.numpy as jnp
+        from repro.kernels.ops import _interpret
+        from repro.kernels.rollup_digest import rollup_chunk_digests
+        # a writable copy: the dirty-chunk refold patches it in place
+        return np.array(rollup_chunk_digests(
+            jnp.asarray(np.ascontiguousarray(words, np.uint32)),
+            chunk_p=chunk, interpret=_interpret()))
     return chunk_fold_digests(words, chunk)
 
 

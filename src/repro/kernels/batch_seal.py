@@ -13,8 +13,8 @@ dedicated kernel for that inner fold, in three interchangeable impls
   * ``batch_seal_pallas`` — segments scattered into a zero-padded
     (n_batches, width) tile (zero words mix to zero and fold away, the
     same padding contract as ``rollup_chunk_digests``), then one Pallas
-    grid pass folds each row — the ``_chunk_kernel`` pattern with a
-    batch per grid step.
+    grid pass folds 8 batch rows per step (``rollup_digest.
+    row_fold_call``, the layout every ledger fold shares).
 
 All three return identical u32 digests for every segmentation (pinned
 by tests/test_kernels.py on the {x64 on/off} CPU matrix in CI).
@@ -26,9 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
 from repro.core.state import MIX_MULT, MIX_SEED
+from repro.kernels.rollup_digest import LANES, row_fold_call
 
 
 def batch_seal_np(words: np.ndarray, starts: np.ndarray) -> np.ndarray:
@@ -56,31 +56,22 @@ def batch_seal_jax(words: np.ndarray, starts: np.ndarray) -> np.ndarray:
                                    jnp.asarray(starts, jnp.int32)))
 
 
-def _seal_kernel(x_ref, o_ref):
-    x = x_ref[...]                                # (1, rows, 128)
-    mixed = jnp.bitwise_xor(x, x >> 16) * jnp.uint32(0x85EBCA6B)
-    o_ref[...] = jax.lax.reduce(mixed, jnp.uint32(0), jnp.bitwise_xor, (1,))
+#: longest row block (u32 words) one batch_seal grid step loads; longer
+#: batches tile over the kernel's second grid axis, so fast memory is
+#: bounded by this and not by the longest batch
+SEAL_BLOCK_W = 8192
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _seal_pallas_call(tiles, *, interpret: bool):
-    nb, rows, lanes = tiles.shape
-    out = pl.pallas_call(
-        _seal_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, rows, lanes), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, lanes), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, lanes), jnp.uint32),
-        interpret=interpret,
-    )(tiles)
-    return jnp.uint32(0x9E3779B9) ^ jax.lax.reduce(
-        out, jnp.uint32(0), jnp.bitwise_xor, (1,))
+@functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
+def _seal_pallas_call(tiles, *, block_w: int, interpret: bool):
+    return row_fold_call(tiles, name="batch_seal", block_w=block_w,
+                         interpret=interpret)
 
 
 def batch_seal_pallas(words: np.ndarray, starts: np.ndarray, *,
                       interpret: bool | None = None) -> np.ndarray:
     """Pallas impl: scatter segments into a zero-padded row per batch
-    (zero words fold away) and fold rows on a per-batch grid."""
+    (zero words fold away) and fold 8 batch rows per grid step."""
     if interpret is None:
         from repro.kernels.ops import _interpret
         interpret = _interpret()
@@ -88,12 +79,12 @@ def batch_seal_pallas(words: np.ndarray, starts: np.ndarray, *,
     starts = np.asarray(starts, np.int64)
     nb = len(starts)
     lens = np.diff(np.concatenate([starts, [len(w)]]))
-    width = max(128, int(-(-int(lens.max()) // 128)) * 128)
+    width = max(LANES, -(-int(lens.max()) // LANES) * LANES)
+    block_w = min(width, SEAL_BLOCK_W)
+    width = -(-width // block_w) * block_w
     tiles = np.zeros((nb, width), np.uint32)
     seg = np.repeat(np.arange(nb), lens)
     tiles[seg, np.arange(len(w)) - starts[seg]] = w
-    lanes = 128
-    out = _seal_pallas_call(
-        jnp.asarray(tiles.reshape(nb, width // lanes, lanes)),
-        interpret=bool(interpret))
+    out = _seal_pallas_call(jnp.asarray(tiles), block_w=block_w,
+                            interpret=bool(interpret))
     return np.asarray(out)

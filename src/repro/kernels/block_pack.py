@@ -5,13 +5,13 @@ calls (head-of-line eligibility on the running-max submit times, then the
 gas cap on the gas cumsum).  The fused window loop (core/fused.py) needs
 the SAME packing decision for every block of a run at once; the carried
 mempool pointer makes the blocks sequentially dependent, so this module
-lowers the whole loop into one ``lax.scan`` (jax impl) or one Pallas
-program (pallas impl) instead of N Python round-trips.
+lowers the whole loop into one ``lax.scan`` (jax impl) instead of N
+Python round-trips.
 
 Bit-exactness across backends: the eligibility compare is on float64
 submit times and the gas cap on int64 cumsums — neither survives a
-float32 downcast (JAX_ENABLE_X64=0) or a TPU (no f64).  Both device
-impls therefore binary-search on a **monotone (hi, lo) u32 pair
+float32 downcast (JAX_ENABLE_X64=0) or a TPU (no f64).  The device
+impl therefore binary-searches on a **monotone (hi, lo) u32 pair
 encoding**: for non-negative IEEE doubles the raw bit pattern orders
 exactly like the value, and a non-negative int64 splits into ordered
 u32 halves, so the pair-lexicographic compare reproduces the NumPy
@@ -19,7 +19,7 @@ float64/int64 ``searchsorted`` decisions bit-for-bit on every backend.
 
 ``block_pack_np`` is the bit-exact NumPy mirror (the per-block
 ``produce_block`` semantics, pinned equal by tests/test_kernels.py);
-all three impls are registered with ``kernels.factory`` under op
+both impls are registered with ``kernels.factory`` under op
 ``"block_pack"``.
 """
 from __future__ import annotations
@@ -30,7 +30,6 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
 
 def _split_f64(x: np.ndarray):
@@ -91,7 +90,7 @@ def block_pack_np(tmax: np.ndarray, gcum: np.ndarray, times: np.ndarray,
     return stops
 
 
-# -- shared pair-compare binary search (jnp; used by the scan impl) ---------
+# -- pair-compare binary search (jnp) ----------------------------------------
 
 def _pair_le(ah, al, bh, bl):
     return (ah < bh) | ((ah == bh) & (al <= bl))
@@ -140,7 +139,7 @@ def _pack_scan(tmax_hi, tmax_lo, gcum_hi, gcum_lo, t_hi, t_lo, n_vis,
 
 
 def _encode(tmax, gcum, times, n_vis, gas_limit, ptr0):
-    """Host-side pair encoding + shape bucketing shared by jax/pallas."""
+    """Host-side pair encoding + shape bucketing for the scan impl."""
     n, b = len(tmax), len(times)
     np_, bp = _bucket(n), _bucket(b)
     tmh, tml = _split_f64(tmax)
@@ -171,73 +170,6 @@ def block_pack_jax(tmax, gcum, times, n_vis, gas_limit, ptr0) -> np.ndarray:
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
         stops = _pack_scan(*enc[:-1], iters=enc[-1])
-    return np.asarray(stops, np.int64)[: len(times)]
-
-
-# -- Pallas impl ------------------------------------------------------------
-
-def _pack_kernel(tmh_ref, tml_ref, gch_ref, gcl_ref, th_ref, tl_ref,
-                 nv_ref, p0_ref, o_ref, *, iters: int, lim_hi: int,
-                 lim_lo: int):
-    n = tmh_ref.shape[0]
-
-    def load(ref, i):
-        return pl.load(ref, (pl.ds(i, 1),))[0]
-
-    def search(hi_ref, lo_ref, vh, vl, lo0, hi0):
-        def body(_, lh):
-            l, h = lh
-            cont = l < h
-            m = (l + h) // 2
-            mi = jnp.minimum(m, n - 1)
-            le = cont & _pair_le(load(hi_ref, mi), load(lo_ref, mi), vh, vl)
-            return (jnp.where(le, m + 1, l),
-                    jnp.where(cont & ~le, m, h))
-        l, _ = jax.lax.fori_loop(0, iters, body, (lo0, hi0))
-        return l
-
-    def block(b, ptr):
-        hi_t = search(tmh_ref, tml_ref, load(th_ref, b), load(tl_ref, b),
-                      jnp.int32(0), jnp.int32(n))
-        hi = jnp.maximum(jnp.minimum(hi_t, load(nv_ref, b)), ptr)
-        pm = jnp.maximum(ptr - 1, 0)
-        has = ptr > 0
-        bh = jnp.where(has, load(gch_ref, pm), jnp.uint32(0))
-        bl = jnp.where(has, load(gcl_ref, pm), jnp.uint32(0))
-        vl = bl + jnp.uint32(lim_lo)
-        vh = bh + jnp.uint32(lim_hi) + (vl < bl).astype(jnp.uint32)
-        stop = search(gch_ref, gcl_ref, vh, vl, ptr, hi)
-        pl.store(o_ref, (pl.ds(b, 1),), stop[None])
-        return stop
-    jax.lax.fori_loop(0, th_ref.shape[0], block, p0_ref[0])
-
-
-@functools.partial(jax.jit, static_argnames=("iters", "lim_hi", "lim_lo",
-                                             "interpret"))
-def _pack_pallas_call(tmh, tml, gch, gcl, th, tl, nv, ptr0, *, iters,
-                      lim_hi, lim_lo, interpret):
-    return pl.pallas_call(
-        functools.partial(_pack_kernel, iters=iters, lim_hi=lim_hi,
-                          lim_lo=lim_lo),
-        out_shape=jax.ShapeDtypeStruct(th.shape, jnp.int32),
-        interpret=interpret,
-    )(tmh, tml, gch, gcl, th, tl, nv, ptr0)
-
-
-def block_pack_pallas(tmax, gcum, times, n_vis, gas_limit, ptr0, *,
-                      interpret: bool | None = None) -> np.ndarray:
-    """Pallas impl: one program, sequential blocks, in-kernel pair binary
-    search (control-heavy by design — packing is a scalar decision chain,
-    not a bandwidth kernel)."""
-    if interpret is None:
-        from repro.kernels.ops import _interpret
-        interpret = _interpret()
-    enc = _encode(tmax, gcum, times, n_vis, gas_limit, ptr0)
-    tmh, tml, gch, gcl, th, tl, nv, lim_hi, lim_lo, ptr0_, iters = enc
-    stops = _pack_pallas_call(
-        tmh, tml, gch, gcl, th, tl, nv,
-        np.asarray([ptr0_], np.int32), iters=iters, lim_hi=int(lim_hi),
-        lim_lo=int(lim_lo), interpret=bool(interpret))
     return np.asarray(stops, np.int64)[: len(times)]
 
 

@@ -20,8 +20,8 @@ Registered with ``kernels.factory`` under op ``"dirty_fold"``:
     a window dirties few chunks, and dispatch overhead beats XLA there);
   * ``jax``    — ONE jitted gather-fold (shapes bucketed to powers of two
     so the jit cache holds one entry per bucket);
-  * ``pallas`` — grid over dirty chunks, each program folds one
-    lane-aligned chunk block (TPU default; ``interpret=True`` off-TPU).
+  * ``pallas`` — grid over dirty chunks, each step folds 8 lane-aligned
+    chunk rows (TPU default; ``interpret=True`` off-TPU).
 """
 from __future__ import annotations
 
@@ -30,7 +30,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
+
+from repro.kernels.rollup_digest import row_fold_call
 
 MIX_MULT = np.uint32(0x85EBCA6B)
 MIX_SEED = np.uint32(0x9E3779B9)
@@ -104,34 +105,17 @@ def dirty_fold_jax(words: np.ndarray, chunk_ids: np.ndarray,
 
 # -- Pallas impl: grid over dirty chunks ------------------------------------
 
-def _fold_kernel(x_ref, o_ref):
-    x = x_ref[...]                                   # (1, rows, 128)
-    mixed = jnp.bitwise_xor(x, x >> 16) * jnp.uint32(0x85EBCA6B)
-    o_ref[...] = jax.lax.reduce(mixed, jnp.uint32(0), jnp.bitwise_xor, (1,))
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _fold_pallas_call(rows3d, interpret: bool):
-    d, r, lanes = rows3d.shape
-    out = pl.pallas_call(
-        _fold_kernel,
-        grid=(d,),
-        in_specs=[pl.BlockSpec((1, r, lanes), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, lanes), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((d, lanes), jnp.uint32),
-        interpret=interpret,
-    )(rows3d)
-    # per-chunk lane fold + seed on host-side jnp (d x 128, tiny)
-    return jnp.uint32(0x9E3779B9) ^ jax.lax.reduce(
-        out, jnp.uint32(0), jnp.bitwise_xor, (1,))
+def _fold_pallas_call(rows2d, interpret: bool):
+    return row_fold_call(rows2d, name="dirty_fold", interpret=interpret)
 
 
 def dirty_fold_pallas(words: np.ndarray, chunk_ids: np.ndarray, chunk: int,
                       *, interpret: bool | None = None) -> np.ndarray:
-    """Pallas impl: the device gathers the dirty chunk rows, then one
-    program per chunk folds its lane-aligned block (the ``rollup_digest``
-    chunk-kernel idiom).  ``chunk`` must be lane-aligned (% 128 == 0) —
-    ``STATE_CHUNK_WORDS`` is."""
+    """Pallas impl: the device gathers the dirty chunk rows, then each
+    grid step folds 8 of them (``rollup_digest.row_fold_call``; the
+    pow2 id bucket is >= 8, so the rows are tile-aligned).  ``chunk``
+    must be lane-aligned (% 128 == 0) — ``STATE_CHUNK_WORDS`` is."""
     assert chunk % 128 == 0, "chunk must be lane-aligned"
     if interpret is None:
         from repro.kernels.ops import _interpret
@@ -142,6 +126,5 @@ def dirty_fold_pallas(words: np.ndarray, chunk_ids: np.ndarray, chunk: int,
     w = _padded(words, chunk)
     ids_b = _bucket_ids(ids)
     rows = jnp.asarray(w.reshape(-1, chunk))[jnp.asarray(ids_b)]
-    out = _fold_pallas_call(rows.reshape(ids_b.size, chunk // 128, 128),
-                            bool(interpret))
+    out = _fold_pallas_call(rows, bool(interpret))
     return np.asarray(out, np.uint32)[: ids.size]

@@ -30,6 +30,9 @@ from __future__ import annotations
 import os
 from typing import Callable, Dict, Tuple
 
+#: the environment variable that forces every "auto" choice to one impl
+IMPL_ENV = "REPRO_KERNEL_IMPL"
+
 _REGISTRY: Dict[str, Dict[str, Callable]] = {}
 _DEFAULTS: Dict[str, Dict[str, str]] = {}      # op -> {"cpu": .., "tpu": ..}
 _LOADED = False
@@ -62,7 +65,6 @@ def _load() -> None:
     register_kernel("block_pack", "numpy", bp.block_pack_np)
     register_kernel("block_pack", "jax", bp.block_pack_jax,
                     cpu_default=True, tpu_default=True)
-    register_kernel("block_pack", "pallas", bp.block_pack_pallas)
 
     # per-batch seal digests (VectorRollup.seal segment fold)
     register_kernel("batch_seal", "numpy", bs.batch_seal_np,
@@ -123,18 +125,29 @@ def available_impls(op: str) -> Tuple[str, ...]:
     return tuple(sorted(_REGISTRY.get(op, {})))
 
 
-def get_kernel(op: str, impl: str | None = None) -> Callable:
-    """Resolve ``op`` to one implementation (see module docstring)."""
+def available_ops() -> Tuple[str, ...]:
     _load()
-    try:
-        table = _REGISTRY[op]
-    except KeyError:
+    return tuple(sorted(_REGISTRY))
+
+
+def resolve_impl(op: str, impl: str | None = None) -> str:
+    """The impl key ``get_kernel(op, impl)`` would return (see module
+    docstring for the selection order)."""
+    _load()
+    if op not in _REGISTRY:
         raise KeyError(f"unknown kernel op {op!r}; "
-                       f"registered: {sorted(_REGISTRY)}") from None
-    choice = impl or os.environ.get("REPRO_KERNEL_IMPL") or "auto"
+                       f"registered: {sorted(_REGISTRY)}")
+    choice = impl or os.environ.get(IMPL_ENV) or "auto"
     if choice == "auto":
         from repro.core.state import tpu_digest_backend
         choice = _DEFAULTS[op]["tpu" if tpu_digest_backend() else "cpu"]
+    return choice
+
+
+def get_kernel(op: str, impl: str | None = None) -> Callable:
+    """Resolve ``op`` to one implementation (see module docstring)."""
+    choice = resolve_impl(op, impl)
+    table = _REGISTRY[op]
     try:
         return table[choice]
     except KeyError:

@@ -48,5 +48,6 @@ def model_distance(local: jnp.ndarray, global_: jnp.ndarray,
         out_specs=pl.BlockSpec((n, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
         interpret=interpret,
+        name="model_distance",
     )(local, g2)
     return jnp.sqrt(sq[:, 0])

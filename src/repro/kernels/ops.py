@@ -1,13 +1,11 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-On this container (CPU) the kernels execute via ``interpret=True``; on TPU
-set ``REPRO_PALLAS_INTERPRET=0`` (the default when a TPU backend is
-detected).  The XLA reference paths (ref.py) remain the numerics oracle and
-the dry-run/roofline path (custom-calls hide FLOPs from cost analysis).
+The kernels run compiled on a TPU backend and in interpret mode
+everywhere else; nothing overrides that choice.  The XLA reference paths
+(ref.py) remain the numerics oracle and the dry-run/roofline path
+(custom-calls hide FLOPs from cost analysis).
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -21,9 +19,6 @@ from repro.kernels.weighted_agg import weighted_agg as _wagg
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
 
 

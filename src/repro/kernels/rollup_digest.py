@@ -13,49 +13,112 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _kernel(x_ref, o_ref):
-    i = pl.program_id(0)
+LANES = 128
+SUBLANES = 8
+_MIX_MULT = 0x85EBCA6B
+_MIX_SEED = 0x9E3779B9
 
-    @pl.when(i == 0)
+
+def _mix(x):
+    return jnp.bitwise_xor(x, x >> 16) * jnp.uint32(_MIX_MULT)
+
+
+def _fold_lane_tiles(x_ref):
+    """xor of the ``(8, 128)`` lane tiles of a ``(8, W)`` block ->
+    ``(8, 128)``.  Static lane-aligned slices only: Mosaic has no
+    ``reduce`` lowering for xor, and whole-tile xors stay on the VPU."""
+    acc = _mix(x_ref[:, pl.ds(0, LANES)])
+    for j in range(1, x_ref.shape[1] // LANES):
+        acc = acc ^ _mix(x_ref[:, pl.ds(j * LANES, LANES)])
+    return acc
+
+
+def row_fold_call(rows2d: jnp.ndarray, *, name: str, interpret: bool,
+                  block_w: int | None = None) -> jnp.ndarray:
+    """Per-row xor-mix digests of a ``(R, W)`` u32 grid: ``(R,)`` u32.
+
+    THE Pallas layout every ledger fold shares (chunk digests, batch
+    seals, dirty chunks): one row per digest on the sublane axis, its
+    words along the lanes.  Rows pad to a multiple of 8 (zero rows,
+    sliced off) and each grid step folds 8 rows into one ``(8, 128)``
+    output tile, so every block is ``(8, 128)``-aligned.  ``block_w``
+    (a multiple of 128 dividing ``W``) tiles long rows over a second
+    grid axis that accumulates into the resident output tile, bounding
+    fast memory by the block and not by the longest row."""
+    r, w = rows2d.shape
+    assert w % LANES == 0, "row width must be lane-aligned"
+    bw = w if block_w is None else block_w
+    assert w % bw == 0 and bw % LANES == 0
+    rp = -(-r // SUBLANES) * SUBLANES
+    if rp != r:
+        rows2d = jnp.pad(rows2d, ((0, rp - r), (0, 0)))
+
+    def kernel(x_ref, o_ref):
+        part = _fold_lane_tiles(x_ref)
+        if bw == w:
+            o_ref[...] = part
+            return
+
+        @pl.when(pl.program_id(1) == 0)
+        def _init():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        o_ref[...] = o_ref[...] ^ part
+
+    out = pl.pallas_call(
+        kernel,
+        grid=(rp // SUBLANES, w // bw),
+        in_specs=[pl.BlockSpec((SUBLANES, bw), lambda i, j: (i, j))],
+        out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rp, LANES), jnp.uint32),
+        interpret=interpret,
+        name=name,
+    )(rows2d)
+    # the last 128-lane fold + seed runs in XLA on the small partials
+    return (jnp.uint32(_MIX_SEED) ^ jax.lax.reduce(
+        out, jnp.uint32(0), jnp.bitwise_xor, (1,)))[:r]
+
+
+def _digest_kernel(x_ref, o_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)   # seed applied in the wrapper
 
-    x = x_ref[...]
-    mixed = jnp.bitwise_xor(x, x >> 16) * jnp.uint32(0x85EBCA6B)
-    # lane-wise fold, then fold the running lane vector into the out block
-    o_ref[...] = jnp.bitwise_xor(
-        o_ref[...],
-        jax.lax.reduce(mixed, jnp.uint32(0), jnp.bitwise_xor, (0,))[None])
+    # fold the block's (8, 128) row tiles into the resident out tile
+    acc = o_ref[...]
+    for j in range(x_ref.shape[0] // SUBLANES):
+        acc = acc ^ _mix(x_ref[pl.ds(j * SUBLANES, SUBLANES), :])
+    o_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("block_p", "interpret"))
 def rollup_digest(buf: jnp.ndarray, block_p: int = 16384,
                   interpret: bool = False) -> jnp.ndarray:
-    """buf: (P,) float32/uint32 buffer -> scalar u32 digest."""
+    """buf: (P,) float32/uint32 buffer -> scalar u32 digest.  ``block_p``
+    must hold whole ``(8, 128)`` tiles (% 1024 == 0)."""
+    assert block_p % (SUBLANES * LANES) == 0, "block must be tile-aligned"
     if buf.dtype != jnp.uint32:
         buf = jax.lax.bitcast_convert_type(buf.astype(jnp.float32), jnp.uint32)
     P = buf.shape[0]
     pad = (-P) % block_p
     if pad:
         buf = jnp.pad(buf, (0, pad))
-    Pp = P + pad
-    lanes = 128
-    rows = Pp // lanes
-    buf2 = buf.reshape(rows, lanes)
-    block_r = max(1, min(rows, block_p // lanes))
+    rows = (P + pad) // LANES
+    block_r = block_p // LANES
 
     out = pl.pallas_call(
-        _kernel,
-        grid=(max(1, rows // block_r),),
-        in_specs=[pl.BlockSpec((block_r, lanes), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, lanes), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, lanes), jnp.uint32),
+        _digest_kernel,
+        grid=(rows // block_r,),
+        in_specs=[pl.BlockSpec((block_r, LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((SUBLANES, LANES), jnp.uint32),
         interpret=interpret,
-    )(buf2)
-    # final lane fold on host-side jnp (tiny); seed applied here so the
-    # lane-broadcast in the kernel cannot cancel it (even lane count)
-    return jnp.uint32(0x9E3779B9) ^ jax.lax.reduce(
-        out[0], jnp.uint32(0), jnp.bitwise_xor, (0,))
+        name="rollup_digest",
+    )(buf.reshape(rows, LANES))
+    # final (8, 128) fold + seed in XLA (tiny); seeding here keeps the
+    # lane-broadcast in the kernel from cancelling it (even lane count)
+    return jnp.uint32(_MIX_SEED) ^ jax.lax.reduce(
+        out.reshape(-1), jnp.uint32(0), jnp.bitwise_xor, (0,))
 
 
 @jax.jit
@@ -73,14 +136,6 @@ def rollup_digest_jax(buf: jnp.ndarray) -> jnp.ndarray:
         mixed, jnp.uint32(0), jnp.bitwise_xor, (0,))
 
 
-def _chunk_kernel(x_ref, o_ref):
-    x = x_ref[...]                                # (1, rows_per_chunk, 128)
-    mixed = jnp.bitwise_xor(x, x >> 16) * jnp.uint32(0x85EBCA6B)
-    # fold the chunk's rows into one lane vector; this block IS the whole
-    # chunk, so no cross-invocation accumulation is needed
-    o_ref[...] = jax.lax.reduce(mixed, jnp.uint32(0), jnp.bitwise_xor, (1,))
-
-
 @functools.partial(jax.jit, static_argnames=("chunk_p", "interpret"))
 def rollup_chunk_digests(buf: jnp.ndarray, chunk_p: int = 2048,
                          interpret: bool = False) -> jnp.ndarray:
@@ -92,7 +147,7 @@ def rollup_chunk_digests(buf: jnp.ndarray, chunk_p: int = 2048,
     mirror, pinned by tests/test_state.py.  chunk_p must be lane-aligned
     (% 128) so each chunk maps to whole VPU rows.
     """
-    assert chunk_p % 128 == 0, "chunk must be lane-aligned"
+    assert chunk_p % LANES == 0, "chunk must be lane-aligned"
     if buf.dtype != jnp.uint32:
         buf = jax.lax.bitcast_convert_type(buf.astype(jnp.float32), jnp.uint32)
     P = buf.shape[0]
@@ -100,22 +155,8 @@ def rollup_chunk_digests(buf: jnp.ndarray, chunk_p: int = 2048,
     pad = (-P) % chunk_p
     if pad:
         buf = jnp.pad(buf, (0, pad))
-    lanes = 128
-    n_chunks = (P + pad) // chunk_p
-    rows = chunk_p // lanes
-    buf3 = buf.reshape(n_chunks, rows, lanes)
-
-    out = pl.pallas_call(
-        _chunk_kernel,
-        grid=(n_chunks,),
-        in_specs=[pl.BlockSpec((1, rows, lanes), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, lanes), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_chunks, lanes), jnp.uint32),
-        interpret=interpret,
-    )(buf3)
-    # per-chunk lane fold + seed on host-side jnp (n_chunks x 128, tiny)
-    return jnp.uint32(0x9E3779B9) ^ jax.lax.reduce(
-        out, jnp.uint32(0), jnp.bitwise_xor, (1,))
+    return row_fold_call(buf.reshape(-1, chunk_p), name="chunk_digests",
+                         interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("width",))

@@ -131,17 +131,15 @@ def shard_seal_jax(words: np.ndarray, starts: np.ndarray,
 
 # -- the same fold over a 1-D "shard" mesh ------------------------------------
 @functools.lru_cache(maxsize=None)
-def _lane_fold_mapped(mesh):
+def lane_fold_mapped(mesh):
     """shard_map the fold over the mesh's "shard" axis: each device owns
     a contiguous block of lane rows; no cross-device collectives — the
     fabric-root merge is the only cross-lane step, and it happens on the
     host (with its wire cost modeled by core/interconnect.py)."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.sharding.specs import shard_lane_spec
     spec = shard_lane_spec()
-    fn = shard_map(_lane_fold, mesh=mesh,
-                   in_specs=(spec, spec), out_specs=spec)
+    fn = jax.shard_map(_lane_fold, mesh=mesh,
+                       in_specs=(spec, spec), out_specs=spec)
     return jax.jit(fn, donate_argnums=(1,))
 
 
@@ -163,5 +161,5 @@ def shard_seal_shard_map(words: np.ndarray, starts: np.ndarray,
                                           np.uint32)])
         sp = np.concatenate([sp, np.zeros((kp - K, sp.shape[1]),
                                           sp.dtype)])
-    out = _lane_fold_mapped(mesh)(jnp.asarray(wp), jnp.asarray(sp))
+    out = lane_fold_mapped(mesh)(jnp.asarray(wp), jnp.asarray(sp))
     return np.asarray(out)[:K, :B]

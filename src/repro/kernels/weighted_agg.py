@@ -50,5 +50,6 @@ def weighted_agg(stacked: jnp.ndarray, scores: jnp.ndarray,
         out_specs=pl.BlockSpec((1, block_p), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, Pp), stacked.dtype),
         interpret=interpret,
+        name="weighted_agg",
     )(s2, stacked, denom)
     return out[0, :P]

@@ -10,25 +10,20 @@ import functools
 import jax
 
 
-def _axis_type_kwargs(n_axes: int):
-    """jax.sharding.AxisType landed after 0.4.x; Auto is that jax's default
-    anyway, so older versions simply omit the kwarg."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto_axes(n_axes: int):
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto_axes(len(axes)))
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh for CPU smoke runs of the sharded code paths."""
-    return jax.make_mesh((1, 1), ("data", "model"), **_axis_type_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=_auto_axes(2))
 
 
 @functools.lru_cache(maxsize=None)
@@ -51,7 +46,7 @@ def make_shard_mesh(max_devices: int | None = None):
     n = n_local_devices()
     if max_devices is not None:
         n = max(1, min(n, max_devices))
-    return jax.make_mesh((n,), ("shard",), **_axis_type_kwargs(1))
+    return jax.make_mesh((n,), ("shard",), axis_types=_auto_axes(1))
 
 
 # TPU v5e hardware constants used by the roofline analysis (per chip).

@@ -74,6 +74,8 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     ap.add_argument("--serve-for", type=float, default=None,
                     help="seconds to serve before a clean shutdown")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     try:
         asyncio.run(_serve(build_spec(args), args.serve_for))
     except KeyboardInterrupt:

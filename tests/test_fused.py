@@ -283,3 +283,42 @@ def test_block_pack_scan_hlo_cost():
     assert 2.0 <= ratio <= 8.0, ratio
     hlo = fused_scan_lowering(1024, 64)
     assert hlo.count("while(") + hlo.count("while (") >= 1
+
+
+# -- chip_smoke.py's comparisons, at a tiny size -------------------------------
+def test_smoke_ledger_auto_equals_forced_mirror(monkeypatch):
+    """The smoke's ledger check: the TPU kernel selection (Pallas in
+    interpret mode here) and the forced NumPy mirrors leave identical
+    fingerprints; a different workload changes them."""
+    from repro.core import state
+    from repro.launch.smoke import (fingerprint_diff, forced_impl,
+                                    ledger_fingerprint, run_ledger)
+    from repro.kernels.factory import resolve_impl
+    monkeypatch.setattr(state, "_ON_TPU", True)
+    assert resolve_impl("batch_seal") == "pallas"
+    auto = ledger_fingerprint(run_ledger(300.0, 3.0, 5000))
+    with forced_impl("numpy"):
+        assert resolve_impl("batch_seal") == "numpy"
+        mirror = ledger_fingerprint(run_ledger(300.0, 3.0, 5000))
+    assert fingerprint_diff(auto, mirror) == []
+    other = ledger_fingerprint(run_ledger(300.0, 3.0, 5000, seed=1))
+    assert {"state_root", "state_words_digest", "window_roots",
+            "batch_digests", "events"} <= set(fingerprint_diff(auto, other))
+
+
+def test_smoke_fl_checks(tiny_world):
+    """The smoke's FL checks on a tiny cohort: the Pallas Eq. 1/Eq. 4
+    kernels agree with ref.py, a corrupted merge is caught, and the
+    malicious trainer ends lowest."""
+    import jax
+    from repro.launch.smoke import agg_errors, malicious_lowest, run_fl
+    model, opt, val, bf, eval_fn, _ = tiny_world
+    node, sch, res = run_fl(model, opt, eval_fn, val, bf, n_trainers=4,
+                            n_tasks=1, rounds=2)
+    assert set(res) == {"task0"} and node.use_pallas_agg
+    errs = agg_errors(sch)
+    assert [e["ok"] for e in errs] == [True]
+    assert malicious_lowest(node)
+    rt = sch.runtimes[0]
+    rt.params = jax.tree.map(lambda x: x + 1.0, rt.params)
+    assert not agg_errors(sch)[0]["ok"]
