@@ -27,6 +27,8 @@ from __future__ import annotations
 import dataclasses
 from typing import ClassVar, List, Optional, Tuple, Type
 
+from repro import obs
+
 
 @dataclasses.dataclass(frozen=True)
 class LedgerEvent:
@@ -200,6 +202,7 @@ class EventLog:
             merged.extend(evs)
             prev = pos
         merged.extend(self._events[prev:])
+        obs.count("events.moved", len(merged))
         # in-place renumber: the log owns its event objects, so rewriting
         # seq on the frozen dataclasses is unobservable to drained readers
         for i, e in enumerate(merged):

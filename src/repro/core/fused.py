@@ -57,6 +57,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.engine import (BlockStats, TxArrays, VectorChain,
                                VectorRollup)
 from repro.core.events import BatchSealed, BlockPacked
@@ -244,22 +245,25 @@ class FusedWindowLoop:
     def execute(self) -> None:
         assert not self._executed, "fused plan already executed"
         self._executed = True
+        obs.count("windows")
         chain, rollup = self.chain, self.rollup
-        preps = self._prepare_seals()
+        with obs.span("ledger.seal"):
+            preps = self._prepare_seals()
         chain_buf: List[TxArrays] = []
 
         def flush_chain():
             if not chain_buf:
                 return
-            if len(chain_buf) == 1:
-                chain.submit_arrays(chain_buf[0])
-            else:
-                chain.submit_arrays(TxArrays(
-                    np.concatenate([b.submit_time for b in chain_buf]),
-                    np.concatenate([b.gas for b in chain_buf]),
-                    np.concatenate([b.fn_id for b in chain_buf]),
-                    np.concatenate([b.sender_id for b in chain_buf]),
-                    chain.fns))
+            with obs.span("ledger.pool"):
+                if len(chain_buf) == 1:
+                    chain.submit_arrays(chain_buf[0])
+                else:
+                    chain.submit_arrays(TxArrays(
+                        np.concatenate([b.submit_time for b in chain_buf]),
+                        np.concatenate([b.gas for b in chain_buf]),
+                        np.concatenate([b.fn_id for b in chain_buf]),
+                        np.concatenate([b.sender_id for b in chain_buf]),
+                        chain.fns))
             chain_buf.clear()
 
         times: List[float] = []
@@ -274,25 +278,29 @@ class FusedWindowLoop:
                 chain_buf.append(entry[1])
             elif op == "seal":
                 flush_chain()
-                if self.fabric is not None:
-                    # lanes seal in shard order, then the fabric merges
-                    # the window — the stepped ShardedRollup.seal()
-                    self.fabric._finish_window(
-                        [self._apply_seal(preps[k][seal_i], lane)
-                         for k, lane in enumerate(self._lanes)])
-                else:
-                    self._apply_seal(preps[0][seal_i], rollup)
+                with obs.span("ledger.seal"):
+                    if self.fabric is not None:
+                        # lanes seal in shard order, then the fabric
+                        # merges the window — the stepped
+                        # ShardedRollup.seal()
+                        self.fabric._finish_window(
+                            [self._apply_seal(preps[k][seal_i], lane)
+                             for k, lane in enumerate(self._lanes)])
+                    else:
+                        self._apply_seal(preps[0][seal_i], rollup)
                 seal_i += 1
             elif op == "pump":
                 flush_chain()
-                rollup.pump(entry[1])
+                with obs.span("ledger.prove"):
+                    rollup.pump(entry[1])
             elif op == "settle":
                 flush_chain()
-                rollup.settle_session()
-                if self.fabric is not None:
-                    rollup.prover.drain()      # fabric-wide forced drain
-                else:
-                    rollup.prover.drain(rollup)
+                with obs.span("ledger.prove"):
+                    rollup.settle_session()
+                    if self.fabric is not None:
+                        rollup.prover.drain()  # fabric-wide forced drain
+                    else:
+                        rollup.prover.drain(rollup)
             elif op == "sync":
                 _, state, ids, rep, bal, stake = entry
                 state.ensure_ids(ids)
@@ -551,11 +559,24 @@ class FusedWindowLoop:
                      markers: List[Tuple[int, int, int]]) -> None:
         """Pack every deferred block in one ``block_pack`` kernel call
         and splice the BlockPacked events to their stepped positions."""
-        chain = self.chain
         if times.shape[0] == 0:
             return
+        with obs.span("ledger.pool"):
+            self.chain._consolidate()
+        obs.count("pack.rows", self.chain._n)
+        with obs.span("ledger.pack"):
+            ntx, gas_used, height0 = self._pack(times, n_vis)
+        with obs.span("ledger.events"):
+            self._splice_block_events(times, ntx, gas_used, height0,
+                                      markers)
+
+    def _pack(self, times: np.ndarray, n_vis: np.ndarray
+              ) -> Tuple[np.ndarray, np.ndarray, int]:
+        """Pack the consolidated mempool into the deferred blocks, stamp
+        confirm times, append the ``BlockStats`` and dispatch handlers.
+        Returns per-block tx counts, gas used and the first new height."""
         from repro.kernels.factory import get_kernel
-        chain._consolidate()
+        chain = self.chain
         nblk = times.shape[0]
         ptr0 = chain._ptr
         stops = np.asarray(get_kernel("block_pack")(
@@ -588,7 +609,7 @@ class FusedWindowLoop:
             chain.blocks.append(blk)
         chain.total_gas += int(gas_used.sum())
         chain._ptr = final
-        self._splice_block_events(times, ntx, gas_used, height0, markers)
+        return ntx, gas_used, height0
 
     def _dispatch_handlers(self, lo: int, hi: int) -> None:
         """Per-(block, fn) handler dispatch — produce_block's contract,

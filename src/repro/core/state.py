@@ -35,6 +35,8 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
+
 # Mixing constants shared with core/engine.py and kernels/rollup_digest.py.
 MIX_MULT = np.uint32(0x85EBCA6B)
 MIX_SEED = np.uint32(0x9E3779B9)
@@ -355,30 +357,31 @@ class StateArrays:
         last call are refolded (``kernels/dirty_fold``) before the sha256
         seal — O(touched) per window instead of O(state).  Pinned equal to
         the full refold by tests/test_state.py."""
-        if not self._track_dirty:
-            return chunked_root(self.word_buffer(), chunk, backend,
-                                header=self.schema_header())
-        cache = self._commit_caches.get(("flat", chunk))
-        if cache is None:
-            words = self.word_buffer()
-            cache = {"words": words,
-                     "digests": _fold_digests(words, chunk, backend),
-                     "pending": []}
-            self._commit_caches[("flat", chunk)] = cache
-        elif cache["pending"]:
-            rows = np.unique(np.concatenate(cache["pending"]))
-            cache["pending"].clear()
-            rows = rows[rows < self.n]
-            if rows.size:
-                touched = self._patch_rows(cache["words"], self.n,
-                                           rows, rows)
-                dirty = np.unique(touched // chunk)
-                from repro.kernels.factory import get_kernel
-                cache["digests"][dirty] = get_kernel(
-                    "dirty_fold", _dirty_impl(backend))(
-                        cache["words"], dirty, chunk)
-        return _seal_digests(self.schema_header(), cache["words"].size,
-                             cache["digests"])
+        with obs.span("ledger.commit"):
+            if not self._track_dirty:
+                return chunked_root(self.word_buffer(), chunk, backend,
+                                    header=self.schema_header())
+            cache = self._commit_caches.get(("flat", chunk))
+            if cache is None:
+                words = self.word_buffer()
+                cache = {"words": words,
+                         "digests": _fold_digests(words, chunk, backend),
+                         "pending": []}
+                self._commit_caches[("flat", chunk)] = cache
+            elif cache["pending"]:
+                rows = np.unique(np.concatenate(cache["pending"]))
+                cache["pending"].clear()
+                rows = rows[rows < self.n]
+                if rows.size:
+                    touched = self._patch_rows(cache["words"], self.n,
+                                               rows, rows)
+                    dirty = np.unique(touched // chunk)
+                    from repro.kernels.factory import get_kernel
+                    cache["digests"][dirty] = get_kernel(
+                        "dirty_fold", _dirty_impl(backend))(
+                            cache["words"], dirty, chunk)
+            return _seal_digests(self.schema_header(), cache["words"].size,
+                                 cache["digests"])
 
     def _patch_rows(self, words: np.ndarray, m: int, rows: np.ndarray,
                     pos: np.ndarray) -> np.ndarray:
@@ -426,45 +429,48 @@ class StateArrays:
         With dirty tracking, each shard's word buffer + digest vector is
         cached and only its dirty chunks refold.
         """
-        headers = [self.schema_header() + f"|shard={k}/{n_shards}".encode()
-                   for k in range(n_shards)]
-        if not self._track_dirty:
-            owner = account_owner(np.arange(self.n), n_shards)
-            return [chunked_root(
-                self._rows_words(np.flatnonzero(owner == k)),
-                chunk, backend, headers[k]) for k in range(n_shards)]
-        cache = self._commit_caches.get(("part", n_shards, chunk))
-        if cache is None:
-            owner = account_owner(np.arange(self.n), n_shards)
-            rows_k = [np.flatnonzero(owner == k) for k in range(n_shards)]
-            words_k = [self._rows_words(r) for r in rows_k]
-            cache = {"rows": rows_k, "words": words_k,
-                     "digests": [_fold_digests(w, chunk, backend)
-                                 for w in words_k],
-                     "pending": []}
-            self._commit_caches[("part", n_shards, chunk)] = cache
-        elif cache["pending"]:
-            rows = np.unique(np.concatenate(cache["pending"]))
-            cache["pending"].clear()
-            rows = rows[rows < self.n]
-            if rows.size:
-                from repro.kernels.factory import get_kernel
-                fold = get_kernel("dirty_fold", _dirty_impl(backend))
-                owner = account_owner(rows, n_shards)
-                for k in range(n_shards):
-                    rk = rows[owner == k]
-                    if not rk.size:
-                        continue
-                    shard_rows = cache["rows"][k]
-                    pos = np.searchsorted(shard_rows, rk)
-                    touched = self._patch_rows(cache["words"][k],
-                                               shard_rows.size, rk, pos)
-                    dirty = np.unique(touched // chunk)
-                    cache["digests"][k][dirty] = fold(
-                        cache["words"][k], dirty, chunk)
-        return [_seal_digests(headers[k], cache["words"][k].size,
-                              cache["digests"][k])
-                for k in range(n_shards)]
+        with obs.span("ledger.commit"):
+            headers = [self.schema_header()
+                       + f"|shard={k}/{n_shards}".encode()
+                       for k in range(n_shards)]
+            if not self._track_dirty:
+                owner = account_owner(np.arange(self.n), n_shards)
+                return [chunked_root(
+                    self._rows_words(np.flatnonzero(owner == k)),
+                    chunk, backend, headers[k]) for k in range(n_shards)]
+            cache = self._commit_caches.get(("part", n_shards, chunk))
+            if cache is None:
+                owner = account_owner(np.arange(self.n), n_shards)
+                rows_k = [np.flatnonzero(owner == k)
+                          for k in range(n_shards)]
+                words_k = [self._rows_words(r) for r in rows_k]
+                cache = {"rows": rows_k, "words": words_k,
+                         "digests": [_fold_digests(w, chunk, backend)
+                                     for w in words_k],
+                         "pending": []}
+                self._commit_caches[("part", n_shards, chunk)] = cache
+            elif cache["pending"]:
+                rows = np.unique(np.concatenate(cache["pending"]))
+                cache["pending"].clear()
+                rows = rows[rows < self.n]
+                if rows.size:
+                    from repro.kernels.factory import get_kernel
+                    fold = get_kernel("dirty_fold", _dirty_impl(backend))
+                    owner = account_owner(rows, n_shards)
+                    for k in range(n_shards):
+                        rk = rows[owner == k]
+                        if not rk.size:
+                            continue
+                        shard_rows = cache["rows"][k]
+                        pos = np.searchsorted(shard_rows, rk)
+                        touched = self._patch_rows(cache["words"][k],
+                                                   shard_rows.size, rk, pos)
+                        dirty = np.unique(touched // chunk)
+                        cache["digests"][k][dirty] = fold(
+                            cache["words"][k], dirty, chunk)
+            return [_seal_digests(headers[k], cache["words"][k].size,
+                                  cache["digests"][k])
+                    for k in range(n_shards)]
 
     def partition_root(self, shard: int, n_shards: int,
                        chunk: int = STATE_CHUNK_WORDS,

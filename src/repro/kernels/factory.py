@@ -15,6 +15,12 @@ Impl keys (per-op subsets of):
   * ``"jax"``    — jitted XLA program (scan / prefix-scan forms).
   * ``"pallas"`` — the Pallas TPU kernel (``interpret=True`` off-TPU).
 
+Every impl but the NumPy mirror copies its NumPy inputs to the device,
+so ``register_kernel`` wraps it: each call runs inside a
+``ledger.kernel.<op>`` span and counts ``kernel.calls.<op>`` and
+``kernel.h2d_bytes.<op>`` (the ``nbytes`` of its NumPy arguments) in
+``repro.obs``.  The mirrors stay unwrapped.
+
 Selection: an explicit ``impl=`` wins; else the ``REPRO_KERNEL_IMPL``
 env var; else ``"auto"`` — the op's registered TPU default on a TPU
 backend, its CPU default otherwise.  Every impl of an op takes and
@@ -27,8 +33,13 @@ tests/test_kernels.py — see docs/KERNELS.md.
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Callable, Dict, Tuple
+
+import numpy as np
+
+from repro import obs
 
 #: the environment variable that forces every "auto" choice to one impl
 IMPL_ENV = "REPRO_KERNEL_IMPL"
@@ -41,8 +52,10 @@ _LOADED = False
 def register_kernel(op: str, impl: str, fn: Callable, *,
                     cpu_default: bool = False,
                     tpu_default: bool = False) -> Callable:
-    """Register ``fn`` as implementation ``impl`` of ``op``."""
-    _REGISTRY.setdefault(op, {})[impl] = fn
+    """Register ``fn`` as implementation ``impl`` of ``op`` (a device
+    impl under its span and counters, see the module docstring)."""
+    _REGISTRY.setdefault(op, {})[impl] = \
+        fn if impl == "numpy" else _traced(op, fn)
     d = _DEFAULTS.setdefault(op, {})
     if cpu_default or "cpu" not in d:
         d["cpu"] = impl
@@ -51,9 +64,23 @@ def register_kernel(op: str, impl: str, fn: Callable, *,
     return fn
 
 
+def _traced(op: str, fn: Callable) -> Callable:
+    name = f"ledger.kernel.{op}"
+    calls, h2d = f"kernel.calls.{op}", f"kernel.h2d_bytes.{op}"
+
+    @functools.wraps(fn)
+    def call(*args, **kwargs):
+        obs.count(calls)
+        obs.count(h2d, sum(a.nbytes for a in args
+                           if isinstance(a, np.ndarray)))
+        with obs.span(name):
+            return fn(*args, **kwargs)
+    return call
+
+
 def _load() -> None:
-    """Lazy one-shot registration of the built-in ledger ops (imports
-    deferred so importing the factory costs nothing)."""
+    """Lazy one-shot registration of the built-in ledger ops (their
+    modules are imported on first use, not with the factory)."""
     global _LOADED
     if _LOADED:
         return

@@ -34,6 +34,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.api.client import NodeClient
 from repro.api.specs import NodeSpec, ServeSpec
 from repro.core.gas import L1_DEFAULT_GAS
@@ -272,6 +273,7 @@ class NodeService:
         out.update(self.admission.counters())
         out["pool_depth"] = len(self.admission.pool)
         out["clock"] = self._clock
+        out["node"] = obs.counters()
         return out
 
 
