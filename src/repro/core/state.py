@@ -196,23 +196,26 @@ def chunk_fold_digests(words: np.ndarray,
     return MIX_SEED ^ np.bitwise_xor.reduce(mixed.reshape(-1, chunk), axis=1)
 
 
-def _fold_digests(words: np.ndarray, chunk: int,
-                  backend: str) -> np.ndarray:
+def _fold_digests(words: np.ndarray, chunk: int, backend: str,
+                  resident=None) -> np.ndarray:
     """Full per-chunk digest vector, routed by ``backend`` ("numpy" forces
     the mirror, "pallas" forces the kernel).  "auto" follows the kernel
     factory's choice for ``dirty_fold`` — the incremental half of the
-    same commitment — so both halves run on the same side."""
+    same commitment — so both halves run on the same side.  On the
+    kernel path the buffer's upload seeds ``resident`` (a commit cache's
+    ``dirty_fold.Resident``), so the refolds that follow stage only the
+    words they touch."""
     if backend == "auto":
         from repro.kernels.factory import resolve_impl
         backend = resolve_impl("dirty_fold")
     if backend == "pallas" and len(words):
-        import jax.numpy as jnp
+        from repro.kernels.dirty_fold import upload
         from repro.kernels.ops import _interpret
         from repro.kernels.rollup_digest import rollup_chunk_digests
         # a writable copy: the dirty-chunk refold patches it in place
         return np.array(rollup_chunk_digests(
-            jnp.asarray(np.ascontiguousarray(words, np.uint32)),
-            chunk_p=chunk, interpret=_interpret()))
+            upload(words, chunk, resident).reshape(-1), chunk_p=chunk,
+            interpret=_interpret()))
     return chunk_fold_digests(words, chunk)
 
 
@@ -238,6 +241,26 @@ def _dirty_impl(backend: str) -> Optional[str]:
     """Map a digest-backend name onto a ``dirty_fold`` factory impl key
     (``None`` lets the factory's own auto/env selection decide)."""
     return backend if backend in ("numpy", "pallas") else None
+
+
+def _refold(words: np.ndarray, touched: np.ndarray, digests: np.ndarray,
+            resident, chunk: int, backend: str) -> None:
+    """Refold the chunks covering the ``touched`` word indices of a
+    commit cache's patched ``words`` into its ``digests``, in place.  A
+    device impl gets the cache's ``resident`` holder and the touched
+    indices, so it stages those words and not the buffer; the mirror
+    folds on the host, and the device copy it leaves behind is dropped,
+    to be uploaded afresh by the next device call."""
+    from repro.kernels.factory import get_kernel, resolve_impl
+    dirty = np.unique(touched // chunk)
+    impl = resolve_impl("dirty_fold", _dirty_impl(backend))
+    fold = get_kernel("dirty_fold", impl)
+    if impl == "numpy":
+        resident.lanes = None
+        digests[dirty] = fold(words, dirty, chunk)
+    else:
+        digests[dirty] = fold(words, dirty, chunk, resident=resident,
+                              touched=touched)
 
 
 # ---------------------------------------------------------------------------
@@ -355,17 +378,21 @@ class StateArrays:
         With dirty tracking enabled the word buffer and per-chunk digest
         vector are cached; only the chunks covering rows touched since the
         last call are refolded (``kernels/dirty_fold``) before the sha256
-        seal — O(touched) per window instead of O(state).  Pinned equal to
-        the full refold by tests/test_state.py."""
+        seal — O(touched) per window instead of O(state).  On a device
+        impl the cache also owns a resident device copy of the words
+        (``dirty_fold.Resident``), patched with just the touched words.
+        Pinned equal to the full refold by tests/test_state.py."""
         with obs.span("ledger.commit"):
             if not self._track_dirty:
                 return chunked_root(self.word_buffer(), chunk, backend,
                                     header=self.schema_header())
             cache = self._commit_caches.get(("flat", chunk))
             if cache is None:
-                words = self.word_buffer()
-                cache = {"words": words,
-                         "digests": _fold_digests(words, chunk, backend),
+                from repro.kernels.dirty_fold import Resident
+                words, resident = self.word_buffer(), Resident()
+                cache = {"words": words, "resident": resident,
+                         "digests": _fold_digests(words, chunk, backend,
+                                                  resident),
                          "pending": []}
                 self._commit_caches[("flat", chunk)] = cache
             elif cache["pending"]:
@@ -375,11 +402,8 @@ class StateArrays:
                 if rows.size:
                     touched = self._patch_rows(cache["words"], self.n,
                                                rows, rows)
-                    dirty = np.unique(touched // chunk)
-                    from repro.kernels.factory import get_kernel
-                    cache["digests"][dirty] = get_kernel(
-                        "dirty_fold", _dirty_impl(backend))(
-                            cache["words"], dirty, chunk)
+                    _refold(cache["words"], touched, cache["digests"],
+                            cache["resident"], chunk, backend)
             return _seal_digests(self.schema_header(), cache["words"].size,
                                  cache["digests"])
 
@@ -427,7 +451,8 @@ class StateArrays:
         These are the per-shard commitments merged into the fabric root
         (core/shards.py); unlike ``root()`` they depend on the partition.
         With dirty tracking, each shard's word buffer + digest vector is
-        cached and only its dirty chunks refold.
+        cached (with a resident device copy of its own) and only its
+        dirty chunks refold.
         """
         with obs.span("ledger.commit"):
             headers = [self.schema_header()
@@ -440,13 +465,16 @@ class StateArrays:
                     chunk, backend, headers[k]) for k in range(n_shards)]
             cache = self._commit_caches.get(("part", n_shards, chunk))
             if cache is None:
+                from repro.kernels.dirty_fold import Resident
                 owner = account_owner(np.arange(self.n), n_shards)
                 rows_k = [np.flatnonzero(owner == k)
                           for k in range(n_shards)]
                 words_k = [self._rows_words(r) for r in rows_k]
+                res_k = [Resident() for _ in range(n_shards)]
                 cache = {"rows": rows_k, "words": words_k,
-                         "digests": [_fold_digests(w, chunk, backend)
-                                     for w in words_k],
+                         "resident": res_k,
+                         "digests": [_fold_digests(w, chunk, backend, r)
+                                     for w, r in zip(words_k, res_k)],
                          "pending": []}
                 self._commit_caches[("part", n_shards, chunk)] = cache
             elif cache["pending"]:
@@ -454,8 +482,6 @@ class StateArrays:
                 cache["pending"].clear()
                 rows = rows[rows < self.n]
                 if rows.size:
-                    from repro.kernels.factory import get_kernel
-                    fold = get_kernel("dirty_fold", _dirty_impl(backend))
                     owner = account_owner(rows, n_shards)
                     for k in range(n_shards):
                         rk = rows[owner == k]
@@ -465,9 +491,9 @@ class StateArrays:
                         pos = np.searchsorted(shard_rows, rk)
                         touched = self._patch_rows(cache["words"][k],
                                                    shard_rows.size, rk, pos)
-                        dirty = np.unique(touched // chunk)
-                        cache["digests"][k][dirty] = fold(
-                            cache["words"][k], dirty, chunk)
+                        _refold(cache["words"][k], touched,
+                                cache["digests"][k], cache["resident"][k],
+                                chunk, backend)
             return [_seal_digests(headers[k], cache["words"][k].size,
                                   cache["digests"][k])
                     for k in range(n_shards)]

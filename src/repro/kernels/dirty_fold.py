@@ -18,10 +18,20 @@ Registered with ``kernels.factory`` under op ``"dirty_fold"``:
 
   * ``numpy``  — reshape + reduce over the selected rows (CPU default:
     a window dirties few chunks, and dispatch overhead beats XLA there);
-  * ``jax``    — ONE jitted gather-fold (shapes bucketed to powers of two
-    so the jit cache holds one entry per bucket);
-  * ``pallas`` — grid over dirty chunks, each step folds 8 lane-aligned
-    chunk rows (TPU default; ``interpret=True`` off-TPU).
+  * ``jax``    — ONE jitted patch-and-fold program (below);
+  * ``pallas`` — the same program, its fold a grid over dirty chunks,
+    each step folding 8 lane-aligned chunk rows (TPU default;
+    ``interpret=True`` off-TPU).
+
+The device impls keep the buffer on the chip.  Each commit cache owns a
+``Resident`` holder; a call hands it in with the word indices its row
+patch touched, and the program scatters just those words into the
+resident copy (donated, so in place), gathers the dirty chunks and
+folds them.  The whole buffer crosses to the device only when the
+holder has no copy of its size: once per cache, counted in
+``kernel.uploads.dirty_fold``.  A call without a holder uploads the
+whole buffer for itself.  The impls count what they stage in
+``kernel.h2d_bytes.dirty_fold``.
 """
 from __future__ import annotations
 
@@ -31,7 +41,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.rollup_digest import row_fold_call
+from repro import obs
+from repro.kernels.rollup_digest import LANES, row_fold_call
+
+#: counters: full-buffer uploads into a holder, and every byte staged
+UPLOADS = "kernel.uploads.dirty_fold"
+H2D = "kernel.h2d_bytes.dirty_fold"
 
 MIX_MULT = np.uint32(0x85EBCA6B)
 MIX_SEED = np.uint32(0x9E3779B9)
@@ -45,8 +60,8 @@ def _padded(words: np.ndarray, chunk: int) -> np.ndarray:
     return w
 
 
-def _bucket(n: int, floor: int = 8) -> int:
-    return max(floor, 1 << max(0, (int(n) - 1).bit_length()))
+def _bucket(n: int) -> int:
+    return max(8, 1 << max(0, (int(n) - 1).bit_length()))
 
 
 # -- NumPy mirror (THE reference semantics) ---------------------------------
@@ -66,65 +81,121 @@ def dirty_fold_np(words: np.ndarray, chunk_ids: np.ndarray,
     return MIX_SEED ^ np.bitwise_xor.reduce(mixed, axis=1)
 
 
-# -- jax impl: one jitted gather-fold ---------------------------------------
+# -- device impls: one patch-and-fold program over a resident buffer ------
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def _gather_fold(words2d, ids, chunk: int):
-    rows = words2d[ids]                              # (Db, chunk) gather
-    mixed = (rows ^ (rows >> jnp.uint32(16))) * jnp.uint32(0x85EBCA6B)
-    return jnp.uint32(0x9E3779B9) ^ jax.lax.reduce(
-        mixed, jnp.uint32(0), jnp.bitwise_xor, (1,))
+class Resident:
+    """The device copy of one commit cache's word buffer.
+
+    ``lanes`` is the chunk-padded buffer as ``(n_words // 128, 128)`` u32
+    rows on the device, or ``None`` before the first upload: lane rows,
+    because a flat word index scatters into them in place, and a chunk
+    is ``chunk // 128`` consecutive rows.  The host buffer stays the
+    source of truth (the mirror and the sha256 seal read it); this copy
+    changes only by the scatters ``dirty_fold`` makes of the words each
+    call names as touched.  Each commit cache owns one, and it dies with
+    the cache."""
+
+    __slots__ = ("lanes",)
+
+    def __init__(self):
+        self.lanes = None
+
+
+def upload(words: np.ndarray, chunk: int,
+           resident: Resident | None = None) -> jax.Array:
+    """Copy the whole chunk-padded buffer to the device as lane rows.
+    Given a holder, the copy becomes its resident buffer and counts one
+    ``kernel.uploads.dirty_fold`` and its bytes in
+    ``kernel.h2d_bytes.dirty_fold``."""
+    lanes = jnp.asarray(_padded(words, chunk).reshape(-1, LANES))
+    if resident is not None:
+        resident.lanes = lanes
+        obs.count(UPLOADS)
+        obs.count(H2D, lanes.nbytes)
+    return lanes
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "fold", "interpret"),
+                   donate_argnums=(0,))
+def _patch_fold(lanes, idx, vals, ids, chunk: int, fold: str,
+                interpret: bool):
+    """Scatter ``vals`` into the resident lane rows at the flat word
+    indices ``idx`` (in place: the buffer is donated), then fold the
+    chunks ``ids``: ``(new buffer, (len(ids),) digests)``.  ``idx`` is
+    strictly increasing; its padding lies past the buffer and is
+    dropped."""
+    lanes = lanes.at[idx // LANES, idx % LANES].set(
+        vals, mode="drop", indices_are_sorted=True, unique_indices=True)
+    picked = lanes.reshape(-1, chunk // LANES, LANES)[ids]  # (Db, .., 128)
+    if fold == "pallas":
+        return lanes, row_fold_call(picked.reshape(-1, chunk),
+                                    name="dirty_fold", interpret=interpret)
+    mixed = (picked ^ (picked >> jnp.uint32(16))) * jnp.uint32(0x85EBCA6B)
+    return lanes, jnp.uint32(0x9E3779B9) ^ jax.lax.reduce(
+        mixed, jnp.uint32(0), jnp.bitwise_xor, (1, 2))
+
+
+def _resident_fold(words, chunk_ids, chunk, resident, touched, fold,
+                   interpret=False) -> np.ndarray:
+    """Bring ``resident`` up to date with ``words`` and fold ``chunk_ids``
+    on the device.  The whole buffer moves only when the holder has none
+    of this size; otherwise only the ``touched`` words (their indices
+    and values) and the ids are staged.  Both counts are bucketed to
+    powers of two, so the jit cache holds one entry per bucket."""
+    assert chunk % LANES == 0, "chunk must be lane-aligned"
+    ids = np.asarray(chunk_ids, np.int64)
+    touched = (np.zeros(0, np.int64) if touched is None
+               else np.asarray(touched, np.int64))
+    if ids.size == 0 and touched.size == 0:
+        return np.zeros(0, np.uint32)
+    if resident is None:
+        resident = Resident()
+    end = -(-len(words) // chunk) * chunk
+    if resident.lanes is None or resident.lanes.size != end:
+        upload(words, chunk, resident)
+    tb = _bucket(touched.size)
+    idx = np.arange(end, end + tb, dtype=np.int32)   # pads: past the end
+    idx[: touched.size] = touched
+    vals = np.zeros(tb, np.uint32)
+    vals[: touched.size] = words[touched]
+    ids_b = _bucket_ids(ids)
+    obs.count(H2D, idx.nbytes + vals.nbytes + ids_b.nbytes)
+    # the buffer is donated: a call that fails leaves no holder copy, so
+    # the next call uploads afresh rather than reading a deleted array
+    lanes, resident.lanes = resident.lanes, None
+    resident.lanes, out = _patch_fold(lanes, idx, vals, ids_b, chunk=chunk,
+                                      fold=fold, interpret=bool(interpret))
+    return np.asarray(out, np.uint32)[: ids.size]
 
 
 def _bucket_ids(ids: np.ndarray) -> np.ndarray:
     """Pad the dirty-id vector to its pow2 bucket (pad ids point at chunk
     0 — their folds are computed and dropped)."""
     db = _bucket(ids.size)
-    out = np.zeros(db, np.int64)
+    out = np.zeros(db, np.int32)
     out[: ids.size] = ids
     return out
 
 
-def dirty_fold_jax(words: np.ndarray, chunk_ids: np.ndarray,
-                   chunk: int) -> np.ndarray:
-    """XLA impl: one jitted gather + row fold; both the chunk-count and
-    the dirty-count axes are bucketed to powers of two so the jit cache
-    holds one entry per bucket, not one per state size."""
-    ids = np.asarray(chunk_ids, np.int64)
-    if ids.size == 0:
-        return np.zeros(0, np.uint32)
-    w = _padded(words, chunk)
-    n_chunks = w.size // chunk
-    cb = _bucket(n_chunks, floor=1)
-    if cb > n_chunks:                   # zero rows fold to MIX_SEED, unused
-        w = np.concatenate([w, np.zeros((cb - n_chunks) * chunk, np.uint32)])
-    out = _gather_fold(jnp.asarray(w.reshape(-1, chunk)),
-                       jnp.asarray(_bucket_ids(ids)), chunk)
-    return np.asarray(out, np.uint32)[: ids.size]
-
-
-# -- Pallas impl: grid over dirty chunks ------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _fold_pallas_call(rows2d, interpret: bool):
-    return row_fold_call(rows2d, name="dirty_fold", interpret=interpret)
+def dirty_fold_jax(words: np.ndarray, chunk_ids: np.ndarray, chunk: int,
+                   *, resident: Resident | None = None,
+                   touched: np.ndarray | None = None) -> np.ndarray:
+    """XLA impl: one jitted program scatters the touched words into the
+    resident buffer, gathers the dirty chunks and folds them.  Without a
+    holder the whole buffer is uploaded for this call alone.  ``chunk``
+    must be lane-aligned (% 128 == 0) — ``STATE_CHUNK_WORDS`` is."""
+    return _resident_fold(words, chunk_ids, chunk, resident, touched, "jax")
 
 
 def dirty_fold_pallas(words: np.ndarray, chunk_ids: np.ndarray, chunk: int,
-                      *, interpret: bool | None = None) -> np.ndarray:
-    """Pallas impl: the device gathers the dirty chunk rows, then each
-    grid step folds 8 of them (``rollup_digest.row_fold_call``; the
-    pow2 id bucket is >= 8, so the rows are tile-aligned).  ``chunk``
-    must be lane-aligned (% 128 == 0) — ``STATE_CHUNK_WORDS`` is."""
-    assert chunk % 128 == 0, "chunk must be lane-aligned"
+                      *, resident: Resident | None = None,
+                      touched: np.ndarray | None = None,
+                      interpret: bool | None = None) -> np.ndarray:
+    """Pallas impl: the same program as ``dirty_fold_jax``, with the
+    gathered chunks folded 8 per grid step (``rollup_digest.row_fold_call``;
+    the pow2 id bucket is >= 8, so the rows are tile-aligned)."""
     if interpret is None:
         from repro.kernels.ops import _interpret
         interpret = _interpret()
-    ids = np.asarray(chunk_ids, np.int64)
-    if ids.size == 0:
-        return np.zeros(0, np.uint32)
-    w = _padded(words, chunk)
-    ids_b = _bucket_ids(ids)
-    rows = jnp.asarray(w.reshape(-1, chunk))[jnp.asarray(ids_b)]
-    out = _fold_pallas_call(rows, bool(interpret))
-    return np.asarray(out, np.uint32)[: ids.size]
+    return _resident_fold(words, chunk_ids, chunk, resident, touched,
+                          "pallas", interpret)

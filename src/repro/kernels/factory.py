@@ -15,11 +15,14 @@ Impl keys (per-op subsets of):
   * ``"jax"``    — jitted XLA program (scan / prefix-scan forms).
   * ``"pallas"`` — the Pallas TPU kernel (``interpret=True`` off-TPU).
 
-Every impl but the NumPy mirror copies its NumPy inputs to the device,
+Every impl but the NumPy mirror stages its NumPy inputs on the device,
 so ``register_kernel`` wraps it: each call runs inside a
 ``ledger.kernel.<op>`` span and counts ``kernel.calls.<op>`` and
-``kernel.h2d_bytes.<op>`` (the ``nbytes`` of its NumPy arguments) in
-``repro.obs``.  The mirrors stay unwrapped.
+``kernel.h2d_bytes.<op>`` in ``repro.obs``.  The wrapper counts the
+``nbytes`` of the NumPy arguments, which such an impl copies whole; an
+impl that keeps data on the device between calls (``dirty_fold`` with
+its resident word buffer) registers with ``counts_h2d=True`` and counts
+the bytes it actually stages itself.  The mirrors stay unwrapped.
 
 Selection: an explicit ``impl=`` wins; else the ``REPRO_KERNEL_IMPL``
 env var; else ``"auto"`` — the op's registered TPU default on a TPU
@@ -51,11 +54,12 @@ _LOADED = False
 
 def register_kernel(op: str, impl: str, fn: Callable, *,
                     cpu_default: bool = False,
-                    tpu_default: bool = False) -> Callable:
+                    tpu_default: bool = False,
+                    counts_h2d: bool = False) -> Callable:
     """Register ``fn`` as implementation ``impl`` of ``op`` (a device
     impl under its span and counters, see the module docstring)."""
     _REGISTRY.setdefault(op, {})[impl] = \
-        fn if impl == "numpy" else _traced(op, fn)
+        fn if impl == "numpy" else _traced(op, fn, counts_h2d)
     d = _DEFAULTS.setdefault(op, {})
     if cpu_default or "cpu" not in d:
         d["cpu"] = impl
@@ -64,15 +68,16 @@ def register_kernel(op: str, impl: str, fn: Callable, *,
     return fn
 
 
-def _traced(op: str, fn: Callable) -> Callable:
+def _traced(op: str, fn: Callable, counts_h2d: bool) -> Callable:
     name = f"ledger.kernel.{op}"
     calls, h2d = f"kernel.calls.{op}", f"kernel.h2d_bytes.{op}"
 
     @functools.wraps(fn)
     def call(*args, **kwargs):
         obs.count(calls)
-        obs.count(h2d, sum(a.nbytes for a in args
-                           if isinstance(a, np.ndarray)))
+        if not counts_h2d:
+            obs.count(h2d, sum(a.nbytes for a in args
+                               if isinstance(a, np.ndarray)))
         with obs.span(name):
             return fn(*args, **kwargs)
     return call
@@ -138,13 +143,15 @@ def _load() -> None:
                     tpu_default=True)
 
     # dirty-chunk refold (StateArrays incremental commitment): digests of
-    # only the chunks a window touched, patched into the cached vector
+    # only the chunks a window touched, patched into the cached vector;
+    # the device impls patch a resident copy of the word buffer
     from repro.kernels import dirty_fold as df
     register_kernel("dirty_fold", "numpy", df.dirty_fold_np,
                     cpu_default=True)
-    register_kernel("dirty_fold", "jax", df.dirty_fold_jax)
+    register_kernel("dirty_fold", "jax", df.dirty_fold_jax,
+                    counts_h2d=True)
     register_kernel("dirty_fold", "pallas", df.dirty_fold_pallas,
-                    tpu_default=True)
+                    tpu_default=True, counts_h2d=True)
 
 
 def available_impls(op: str) -> Tuple[str, ...]:

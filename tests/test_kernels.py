@@ -245,6 +245,66 @@ def test_dirty_fold_empty_ids():
         assert out.shape == (0,) and out.dtype == np.uint32
 
 
+def _resident_impl(name):
+    from repro.kernels.dirty_fold import dirty_fold_jax, dirty_fold_pallas
+    if name == "pallas":
+        return lambda *a, **k: dirty_fold_pallas(*a, interpret=True, **k)
+    return dirty_fold_jax
+
+
+@pytest.mark.parametrize("impl", ["jax", "pallas"])
+@pytest.mark.parametrize("n_words,seed", [(5_000, 11), (70_000, 12)])
+def test_dirty_fold_resident_windows_match_mirror(impl, n_words, seed):
+    """A holder over a sequence of windows of random word patches: each
+    window stages only its touched words, the digests equal the mirror's
+    at every step, the device copy equals the host buffer at the end, and
+    the whole buffer was uploaded once."""
+    from repro import obs
+    from repro.core.state import STATE_CHUNK_WORDS as C
+    from repro.kernels.dirty_fold import UPLOADS, Resident, dirty_fold_np
+    fold = _resident_impl(impl)
+    g = np.random.default_rng(seed)
+    words = g.integers(0, 2**32, n_words, dtype=np.uint64).astype(np.uint32)
+    res = Resident()
+    obs.reset()
+    for _ in range(6):
+        touched = np.unique(g.integers(0, n_words, g.integers(1, 300)))
+        words[touched] = g.integers(0, 2**32, touched.size,
+                                    dtype=np.uint64).astype(np.uint32)
+        ids = np.unique(touched // C)
+        got = fold(words, ids, C, resident=res, touched=touched)
+        np.testing.assert_array_equal(got, dirty_fold_np(words, ids, C))
+    host = np.asarray(res.lanes).ravel()
+    np.testing.assert_array_equal(host[:n_words], words)
+    assert not host[n_words:].any()            # the padded tail stays zero
+    assert obs.counters()[UPLOADS] == 1
+    obs.reset()
+
+
+@pytest.mark.parametrize("impl", ["jax", "pallas"])
+def test_dirty_fold_resident_window_with_no_touched_words(impl):
+    """Nothing touched and nothing dirty: no device work, no upload.
+    Dirty ids with nothing touched fold the resident copy as it stands."""
+    from repro import obs
+    from repro.core.state import STATE_CHUNK_WORDS as C
+    from repro.kernels.dirty_fold import UPLOADS, Resident, dirty_fold_np
+    fold = _resident_impl(impl)
+    words = np.arange(3 * C, dtype=np.uint32)
+    none = np.empty(0, np.int64)
+    res = Resident()
+    obs.reset()
+    out = fold(words, none, C, resident=res, touched=none)
+    assert out.shape == (0,) and out.dtype == np.uint32
+    assert res.lanes is None and UPLOADS not in obs.counters()
+    ids = np.array([0, 2])
+    for _ in range(2):
+        np.testing.assert_array_equal(
+            fold(words, ids, C, resident=res, touched=none),
+            dirty_fold_np(words, ids, C))
+    assert obs.counters()[UPLOADS] == 1
+    obs.reset()
+
+
 @pytest.mark.parametrize("n", [0, 1, 7, 513, 4096])
 def test_rollup_digest_factory_impls_bit_exact(n):
     """The factory's three rollup_digest impls agree bit-for-bit with the

@@ -4,7 +4,8 @@ One CPU-size ``FusedWindowLoop`` window over the default node: under
 ``jax.profiler.trace`` every layer span shows and nests as the layers
 do (kernel call in commitment in seal); with the device impls selected
 the window counts itself, its kernel calls and the bytes each call
-copies to the device; the NumPy mirrors stay unwrapped and uncounted;
+copies to the device (the committed words once, then only what a window
+touched); the NumPy mirrors stay unwrapped and uncounted;
 the node service reports the counters under ``"node"``.
 """
 import asyncio
@@ -36,10 +37,10 @@ def _fresh_counters():
     obs.reset()
 
 
-def _node():
+def _node(n_accounts=N_ACCOUNTS):
     client = NodeClient.from_spec(NodeSpec())
     state = client._state_arrays()
-    state.ensure(N_ACCOUNTS)
+    state.ensure(n_accounts)
     client.state_root()                 # first full root: caches the words
     return client, state
 
@@ -101,18 +102,28 @@ def test_window_spans_nest_as_the_layers(monkeypatch, tmp_path):
 
 
 def test_one_window_counts_itself_its_kernels_and_their_bytes(monkeypatch):
+    """Over two windows: the refold keeps the committed words on the
+    device, so after the first window (which uploads them once) a window
+    stages only the words it touched, well under 1% of the buffer at
+    2^18 accounts."""
     monkeypatch.setenv("REPRO_KERNEL_IMPL", "jax")
-    client, state = _node()
+    client, state = _node(1 << 18)
     obs.reset()
     _window(client)
     c = obs.counters()
     assert c["windows"] == 1
     for op in ("batch_seal", "dirty_fold", "block_pack"):
         assert c[f"kernel.calls.{op}"] > 0, op
-    words = state._commit_caches[("flat", STATE_CHUNK_WORDS)]["words"]
-    assert c["kernel.h2d_bytes.dirty_fold"] >= words.nbytes
     assert c["pack.rows"] == client.chain._n > 0
     assert c["events.moved"] >= len(client.chain.events.since(0))
+    words = state._commit_caches[("flat", STATE_CHUNK_WORDS)]["words"]
+    _window(client, seed=1)
+    c2 = obs.counters()
+    assert c2["windows"] == 2
+    staged = (c2["kernel.h2d_bytes.dirty_fold"]
+              - c["kernel.h2d_bytes.dirty_fold"])
+    assert 0 < staged < 0.01 * words.nbytes
+    assert c2["kernel.uploads.dirty_fold"] <= 1
 
 
 def test_numpy_mirrors_are_not_counted(monkeypatch):

@@ -162,6 +162,100 @@ def test_incremental_partition_roots_pinned_and_growth_invalidates():
     assert s.partition_roots(3) == s.copy().partition_roots(3)
 
 
+def _uploads():
+    from repro import obs
+    from repro.kernels.dirty_fold import UPLOADS
+    return obs.counters().get(UPLOADS, 0)
+
+
+def _touch(s, rng, n_rows):
+    ids = rng.integers(0, s.n, n_rows)
+    s.balances[ids] += 1.5
+    s.reputation[ids] = rng.random(n_rows, dtype=np.float32)
+    s.rep_events[ids] += 1
+    s.mark_dirty(ids)
+
+
+@pytest.fixture(params=["jax", "pallas"])
+def device_impl(request, monkeypatch):
+    """Force the device impls of ``dirty_fold`` (Pallas interpreted off
+    the chip) and count afresh."""
+    from repro import obs
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", request.param)
+    obs.reset()
+    yield request.param
+    obs.reset()
+
+
+def test_resident_root_pinned_over_windows_one_upload(device_impl):
+    """The flat cache's resident device copy: roots equal the full
+    refold after every window (and after an empty one), the device copy
+    equals the host buffer, and the buffer is uploaded once in all."""
+    rng = np.random.default_rng(21)
+    s = StateArrays(3000)
+    s.enable_dirty_tracking()
+    assert s.root() == s.copy().root()
+    for _ in range(5):
+        _touch(s, rng, 50)
+        assert s.root() == s.copy().root()
+    assert s.root() == s.copy().root()          # a window touching nothing
+    cache = s._commit_caches[("flat", 2048)]
+    host = np.asarray(cache["resident"].lanes).ravel()
+    np.testing.assert_array_equal(host[: cache["words"].size],
+                                  cache["words"])
+    assert _uploads() == 1
+
+
+def test_resident_cache_rebuilt_after_growth_uploads_once(device_impl):
+    rng = np.random.default_rng(22)
+    s = StateArrays(1500)
+    s.enable_dirty_tracking()
+    s.root()
+    _touch(s, rng, 30)
+    assert s.root() == s.copy().root()
+    assert _uploads() == 1
+    s.ensure(5000)                  # new layout: the cache and holder drop
+    s.stake[4999] = 3.0
+    s.mark_dirty(np.array([4999]))
+    assert s.root() == s.copy().root()
+    for _ in range(3):
+        _touch(s, rng, 40)
+        assert s.root() == s.copy().root()
+    assert _uploads() == 2
+
+
+def test_resident_partition_roots_four_shards(device_impl):
+    """Each of the 4 shard caches keeps its own device copy: one upload
+    per shard over every window, roots equal to the full refold."""
+    rng = np.random.default_rng(23)
+    s = StateArrays(4000)
+    s.enable_dirty_tracking()
+    assert s.partition_roots(4) == s.copy().partition_roots(4)
+    for _ in range(5):
+        _touch(s, rng, 60)
+        assert s.partition_roots(4) == s.copy().partition_roots(4)
+    assert _uploads() == 4
+
+
+def test_resident_copy_dropped_when_the_mirror_refolds(device_impl,
+                                                       monkeypatch):
+    """A window refolded by the NumPy mirror patches only the host
+    buffer; the next device refold must not fold a stale device copy."""
+    rng = np.random.default_rng(24)
+    s = StateArrays(2000)
+    s.enable_dirty_tracking()
+    s.root()
+    _touch(s, rng, 30)
+    assert s.root() == s.copy().root()
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "numpy")
+    _touch(s, rng, 30)
+    assert s.root() == s.copy().root()
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", device_impl)
+    _touch(s, rng, 30)
+    assert s.root() == s.copy().root()
+    assert _uploads() == 2
+
+
 def test_ledger_faces_enable_tracking_and_stay_pinned():
     """Every engine face opts its StateArrays into dirty tracking at
     register_state, and the roots it reports stay equal to an untracked

@@ -8,6 +8,10 @@ shapes ``chip_smoke.py`` produces:
 
   * the ledger kernels at its ledger phase (3000 tx/s x 20 s = ~60k txs
     in 20-tx batches over 100,000 accounts, 11 u32 words per account);
+  * the resident ``dirty_fold`` patch-and-fold program at the benchmark
+    cell's window (2^20 accounts: 5,632 chunks of 2,048 u32 words held
+    as 128-lane rows, 16,384 touched words, 4,096 dirty chunk ids, each
+    count's pow2 bucket);
   * ``weighted_agg`` and ``model_distance`` at LeNet-5's parameter count
     (61,706) over its 8-trainer cohort.
 
@@ -50,12 +54,13 @@ def one_chip():
 def _case(name):
     """(kernel callable, [(shape, dtype), ...]) for one compile case."""
     from repro.kernels.batch_seal import SEAL_BLOCK_W, _seal_pallas_call
-    from repro.kernels.dirty_fold import _fold_pallas_call
+    from repro.kernels.dirty_fold import _patch_fold
+    from repro.kernels.rollup_digest import row_fold_call
     from repro.kernels.model_distance import model_distance
     from repro.kernels.rollup_digest import (rollup_chunk_digests,
                                              rollup_digest)
     from repro.kernels.weighted_agg import weighted_agg
-    u32, f32 = jnp.uint32, jnp.float32
+    u32, i32, f32 = jnp.uint32, jnp.int32, jnp.float32
     return {
         # merged-buffer digest over the whole state word buffer
         "rollup_digest": (rollup_digest, [((STATE_WORDS,), u32)]),
@@ -74,8 +79,17 @@ def _case(name):
                                         interpret=False),
             [((24, 2 * SEAL_BLOCK_W), u32)]),
         # dirty chunks of a window at 100k accounts (pow2 id bucket)
-        "dirty_fold": (lambda r: _fold_pallas_call(r, False),
+        "dirty_fold": (lambda r: row_fold_call(r, name="dirty_fold",
+                                               interpret=False),
                        [((1024, 2048), u32)]),
+        # the benchmark cell's window: scatter ~11k touched words (pow2
+        # bucket) into the resident 2^20-account buffer, fold ~3,700
+        # dirty chunks (bucket 4,096)
+        "dirty_fold_resident": (
+            lambda b, i, v, d: _patch_fold(b, i, v, d, chunk=2048,
+                                           fold="pallas", interpret=False),
+            [((5632 * 16, 128), u32), ((16384,), i32), ((16384,), u32),
+             ((4096,), i32)]),
         "weighted_agg": (weighted_agg, [((8, LENET5_PARAMS), f32),
                                         ((8,), f32)]),
         "model_distance": (model_distance, [((8, LENET5_PARAMS), f32),
@@ -85,7 +99,8 @@ def _case(name):
 
 @pytest.mark.parametrize("name", [
     "rollup_digest", "rollup_chunk_digests", "batch_seal",
-    "batch_seal_long_rows", "dirty_fold", "weighted_agg", "model_distance",
+    "batch_seal_long_rows", "dirty_fold", "dirty_fold_resident",
+    "weighted_agg", "model_distance",
 ])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = _case(name)
