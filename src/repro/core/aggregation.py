@@ -23,7 +23,12 @@ def weighted_average_flat(stacked: jnp.ndarray, scores: jnp.ndarray,
         return weighted_agg(stacked, scores)
     s = scores.astype(jnp.float32)
     denom = jnp.maximum(jnp.sum(s), 1e-12)
-    return (jnp.einsum("np,n->p", stacked.astype(jnp.float32), s)
+    # full f32 products: at the TPU's default precision one bf16 pass
+    # rounds every weight to 8 bits, an error as large as one trainer's
+    # share of a 128-trainer merge; the merge is bound by reading the
+    # weights, so the extra passes cost nothing measurable
+    return (jnp.einsum("np,n->p", stacked.astype(jnp.float32), s,
+                       precision=jax.lax.Precision.HIGHEST)
             / denom).astype(stacked.dtype)
 
 

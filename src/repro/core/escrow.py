@@ -43,8 +43,9 @@ class Escrow:
         pot = sum(self.locked.pop(task_id, {}).values())
         total = sum(s for s in scores.values() if s > min_score)
         payouts: Dict[str, float] = {}
+        held = self.collateral.pop(task_id, {})
         for trainer, score in scores.items():
-            coll = self.collateral.get(task_id, {}).pop(trainer, 0.0)
+            coll = held.pop(trainer, 0.0)
             if score > min_score and total > 0:
                 pay = pot * score / total
                 payouts[trainer] = pay
@@ -53,4 +54,7 @@ class Escrow:
             else:
                 payouts[trainer] = 0.0
                 self.slashed_pool += coll
+        if held:
+            # collateral of trainers the scores do not name stays locked
+            self.collateral[task_id] = held
         return payouts

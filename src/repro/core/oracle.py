@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 
 @dataclasses.dataclass(frozen=True)
 class DONConfig:
@@ -54,6 +56,9 @@ class ValidationSlices:
         sizes = {int(jax.tree.leaves(sl)[0].shape[0]) for sl in self.slices}
         self.stacked = (jax.tree.map(lambda *xs: jnp.stack(xs), *self.slices)
                         if len(sizes) == 1 else None)
+        #: images over every oracle's slice: one submission's evaluation
+        self.n_images = sum(int(jax.tree.leaves(sl)[0].shape[0])
+                            for sl in self.slices)
 
     def __len__(self) -> int:
         return len(self.slices)
@@ -141,32 +146,96 @@ def _score_table_batched(eval_fn: Callable, stacked,
     return np.asarray(table, np.float64)
 
 
-def _mega_eval(eval_fn: Callable):
-    """Jitted task x oracle x trainer TRIPLE-vmapped form of ``eval_fn``
+def _mega_eval(eval_fn: Callable, chunk: int):
+    """Jitted triple-vmapped (task x oracle x trainer) form of ``eval_fn``
     (the cross-task megastep scoring pass), cached beside the per-task
-    wrappers.  Per-trainer independence makes every (task, oracle,
-    trainer) cell bit-exact equal to the per-task double-vmap's cell."""
+    wrappers: params leaves (T, K, ...) and stacked slices (O, V, ...) ->
+    (T, O, K).  ``chunk`` trainers at a time: the trainer axis is split
+    into chunks that ``lax.map`` scores one after another inside the same
+    program (one dispatch), so the activations of only one chunk are live
+    at once.  Per-trainer independence makes every (task, oracle, trainer)
+    cell equal to the per-task double-vmap's cell, whatever the chunk."""
     key = _eval_cache_key(eval_fn)
-    mkey = None if key is None else ("mega", key)
+    mkey = None if key is None else ("mega", key, chunk)
     hit = _eval_cache_get(mkey)
     if hit is not None:
         return hit
-    fn = jax.jit(jax.vmap(
+    triple = jax.vmap(
         jax.vmap(jax.vmap(eval_fn, in_axes=(0, None)), in_axes=(None, 0)),
-        in_axes=(0, None)))
+        in_axes=(0, None))
+
+    def mega_score(params, val):
+        lead = jax.tree.leaves(params)[0].shape[:2]
+        n = lead[1] // chunk
+        split = jax.tree.map(
+            lambda l: jnp.moveaxis(
+                l.reshape((lead[0], n, chunk) + l.shape[2:]), 1, 0), params)
+        out = jax.lax.map(lambda p: triple(p, val), split)  # (n, T, O, c)
+        return jnp.moveaxis(out, 0, 2).reshape(out.shape[1:3] + (-1,))
+    fn = jax.jit(mega_score)
     _eval_cache_put(mkey, fn)
     return fn
+
+
+#: trainers per chunk of the program that measures a trainer's memory
+#: (a one-trainer program lays its activations out differently)
+PROBE_CHUNK = 8
+#: share of the device's memory the scoring pass's temporaries may take
+SCORE_MEMORY_SHARE = 0.5
+
+
+def score_chunk(n: int, per_trainer: float, budget: float) -> int:
+    """The largest divisor of ``n`` trainers whose temporaries
+    (``per_trainer`` bytes each) fit ``budget`` bytes; 1 at least."""
+    return max(c for c in range(1, n + 1)
+               if n % c == 0 and (c == 1 or c * per_trainer <= budget))
+
+
+def _mega_scorer(eval_fn: Callable, mega_stacked, val: ValidationSlices):
+    """The compiled mega scoring program for these shapes, scoring as many
+    trainers at a time as fit ``SCORE_MEMORY_SHARE`` of the device's
+    memory.  Where the backend reports a memory limit, a program of
+    ``PROBE_CHUNK`` trainers at a time is compiled first and its
+    temporary bytes per trainer size the chunk (``score_chunk``); where
+    it reports none, every trainer is one chunk."""
+    leaf = jax.tree.leaves(mega_stacked)[0]
+    key = _eval_cache_key(eval_fn)
+    shapes = tuple((l.shape, str(l.dtype)) for l in
+                   jax.tree.leaves((mega_stacked, val.stacked)))
+    ckey = None if key is None else ("scorer", key, shapes)
+    hit = _eval_cache_get(ckey)
+    if hit is not None:
+        return hit
+    n = int(leaf.shape[1])
+    dev = next(iter(leaf.devices())) if hasattr(leaf, "devices") \
+        else jax.devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    args = (mega_stacked, val.stacked)
+    chunk, prog = n, None
+    if limit:
+        probe = score_chunk(n, 1.0, min(PROBE_CHUNK, n))
+        prog = _mega_eval(eval_fn, probe).lower(*args).compile()
+        per = prog.memory_analysis().temp_size_in_bytes / probe
+        chunk = score_chunk(n, per, SCORE_MEMORY_SHARE * limit)
+        if chunk != probe:
+            prog = None
+    if prog is None:
+        prog = _mega_eval(eval_fn, chunk).lower(*args).compile()
+    _eval_cache_put(ckey, prog)
+    return prog
 
 
 def mega_score_tables(eval_fn: Callable, mega_stacked,
                       val: ValidationSlices) -> np.ndarray:
     """(n_tasks, n_oracles, n_trainers) score tables for a whole stacked
-    task batch in ONE dispatch.  Requires equal-sized oracle slices
-    (``val.stacked``); the caller falls back to per-task quorum calls
-    otherwise."""
+    task batch in ONE dispatch, scored in trainer chunks that fit the
+    device's memory (``_mega_scorer``).  Requires equal-sized oracle
+    slices (``val.stacked``); the caller falls back to per-task quorum
+    calls otherwise."""
     assert val.stacked is not None, "mega scoring needs stacked val slices"
-    return np.asarray(_mega_eval(eval_fn)(mega_stacked, val.stacked),
-                      np.float64)
+    with obs.span("fl.score"):
+        fn = _mega_scorer(eval_fn, mega_stacked, val)
+        return np.asarray(fn(mega_stacked, val.stacked), np.float64)
 
 
 def quorum_from_table(table: np.ndarray, cfg: DONConfig = DONConfig(),
@@ -189,7 +258,9 @@ def quorum_from_table(table: np.ndarray, cfg: DONConfig = DONConfig(),
         "table": table, "median": median, "oracle_deviation": dev,
         "flagged_oracles": flagged, "quorum_ok": bool(quorum_ok),
     }
-    return jnp.asarray(median, jnp.float32), report
+    # host float32: the median is host data, and a device copy here would
+    # compile a conversion for every submitter count a round can have
+    return np.asarray(median, np.float32), report
 
 
 def _score_table_loop(eval_fn: Callable, stacked, n_trainers: int,
@@ -219,31 +290,32 @@ def evaluate_quorum(eval_fn: Callable, trainer_params,
     slices: pre-built ValidationSlices (otherwise split from val_batch).
     Returns (scores (n_trainers,), report).
     """
-    val = slices or ValidationSlices(val_batch, cfg.n_oracles)
-    assert len(val) == cfg.n_oracles
-    stacked, n_trainers = stack_trainer_params(trainer_params)
-    table = None
-    key = _eval_cache_key(eval_fn)
-    if mode == "batched" and _eval_cache_get(key) is _UNBATCHABLE:
-        # forced retry: clear the stale verdict FIRST so the wrappers the
-        # attempt builds get cached (a later auto call reuses them)
-        _BATCHED_EVAL_CACHE.pop(key, None)
-    if mode == "batched" or (mode == "auto"
-                             and _eval_cache_get(key) is not _UNBATCHABLE):
-        try:
-            table = _score_table_batched(eval_fn, stacked, val)
-        except Exception:
-            if mode == "batched":
-                raise
-            # remember the verdict: "auto" must not pay a fresh vmap trace
-            # + swallowed exception on every later quorum round.  Trade-off
-            # (deliberate): a transient first-call failure also demotes the
-            # eval_fn for the process lifetime — force mode="batched" once
-            # to clear a stale verdict
-            _eval_cache_put(key, _UNBATCHABLE)
-    if table is None:
-        table = _score_table_loop(eval_fn, stacked, n_trainers, val.slices)
-    return quorum_from_table(table, cfg, adversarial_oracles)
+    with obs.span("fl.score"):
+        val = slices or ValidationSlices(val_batch, cfg.n_oracles)
+        assert len(val) == cfg.n_oracles
+        stacked, n_trainers = stack_trainer_params(trainer_params)
+        table = None
+        key = _eval_cache_key(eval_fn)
+        if mode == "batched" and _eval_cache_get(key) is _UNBATCHABLE:
+            # forced retry: clear the stale verdict FIRST so the wrappers the
+            # attempt builds get cached (a later auto call reuses them)
+            _BATCHED_EVAL_CACHE.pop(key, None)
+        if mode == "batched" or (mode == "auto"
+                                 and _eval_cache_get(key) is not _UNBATCHABLE):
+            try:
+                table = _score_table_batched(eval_fn, stacked, val)
+            except Exception:
+                if mode == "batched":
+                    raise
+                # remember the verdict: "auto" must not pay a fresh vmap trace
+                # + swallowed exception on every later quorum round.  Trade-off
+                # (deliberate): a transient first-call failure also demotes the
+                # eval_fn for the process lifetime — force mode="batched" once
+                # to clear a stale verdict
+                _eval_cache_put(key, _UNBATCHABLE)
+        if table is None:
+            table = _score_table_loop(eval_fn, stacked, n_trainers, val.slices)
+        return quorum_from_table(table, cfg, adversarial_oracles)
 
 
 def cross_verify_aggregate(agg_fn: Callable, stacked_params, scores,

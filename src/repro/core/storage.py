@@ -46,6 +46,16 @@ class BlobStore:
         assert content_id(blob) == cid, "content hash mismatch (tampering?)"
         return pickle.loads(blob)
 
+    def drop(self, cids) -> None:
+        """Unpin content ids (missing ones are ignored)."""
+        for cid in cids:
+            if self.spill_dir:
+                path = os.path.join(self.spill_dir, cid)
+                if os.path.exists(path):
+                    os.remove(path)
+            else:
+                self._mem.pop(cid, None)
+
     def has(self, cid: str) -> bool:
         if self.spill_dir:
             return os.path.exists(os.path.join(self.spill_dir, cid))

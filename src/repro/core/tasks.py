@@ -100,6 +100,16 @@ class TaskContract:
         if task.current_round >= task.rounds_total:
             task.state = "evaluated"
 
+    def retire_models(self, task_id: str) -> List[str]:
+        """Forget a closed task's per-round model submissions; returns
+        their content ids, for the caller to unpin from the blob store."""
+        task = self.tasks[task_id]
+        assert task.state == "closed", "retire_models before close_task"
+        cids = sorted({c for per in task.models.values()
+                       for c in per.values()})
+        task.models.clear()
+        return cids
+
     def record_scores(self, task_id: str, scores: Dict[str, float]):
         task = self.tasks[task_id]
         task.scores.update(scores)

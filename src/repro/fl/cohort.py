@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.storage import BlobStore
 from repro.fl.dp import DPConfig, privatize
 
@@ -37,6 +38,10 @@ class CohortSubmissions:
     idxs: List[int]          # cohort indices that submitted, ascending
     stacked: Any             # pytree, leaves (len(idxs), ...) in idx order
     cids: Dict[int, str]     # per-idx content id of the submitted blob
+    # device pytree, leaves (selection size, ...): ``stacked``'s rows then
+    # zero rows, so the Eq. 1 merge and the Eq. 4 distances keep one shape
+    # however many trainers skipped the round (None: pad on demand)
+    padded: Any = None
 
     def tree_for(self, k: int):
         """Per-trainer view (k indexes ``idxs``, not the cohort)."""
@@ -63,21 +68,22 @@ class AgentCohort:
 
     def train(self, global_params, rnd: int,
               sel_idx: Sequence[int]) -> Optional[CohortSubmissions]:
-        subs: Dict[int, Dict] = {}
-        for i in sel_idx:
-            out = self.agents[i].train_round(global_params, self._opt[i],
-                                             i, rnd)
-            if out is None:
-                continue
-            self._opt[i] = out["opt_state"]
-            subs[i] = out
-        if not subs:
-            return None
-        idxs = sorted(subs)
-        stacked = jax.tree.map(lambda *xs: jnp.stack(xs),
-                               *[subs[i]["params"] for i in idxs])
-        return CohortSubmissions(idxs, stacked,
-                                 {i: subs[i]["cid"] for i in idxs})
+        with obs.span("fl.train"):
+            subs: Dict[int, Dict] = {}
+            for i in sel_idx:
+                out = self.agents[i].train_round(global_params, self._opt[i],
+                                                 i, rnd)
+                if out is None:
+                    continue
+                self._opt[i] = out["opt_state"]
+                subs[i] = out
+            if not subs:
+                return None
+            idxs = sorted(subs)
+            stacked = jax.tree.map(lambda *xs: jnp.stack(xs),
+                                   *[subs[i]["params"] for i in idxs])
+            return CohortSubmissions(idxs, stacked,
+                                     {i: subs[i]["cid"] for i in idxs})
 
 
 def batched_batch_fn(raw_batch_fn: Callable[[int, int], Dict],
@@ -93,6 +99,36 @@ def batched_batch_fn(raw_batch_fn: Callable[[int, int], Dict],
         return {k: jnp.stack([jnp.stack([np.asarray(b[k]) for b in row])
                               for row in per]) for k in keys}
     return fn
+
+
+def pad_rows(stacked, n: int):
+    """Host copy of a stacked pytree with zero rows appended up to ``n``
+    rows (the merge's fixed shape when no ``padded`` copy was kept)."""
+    def one(l):
+        l = np.asarray(l)
+        return np.concatenate(
+            [l, np.zeros((n - l.shape[0],) + l.shape[1:], l.dtype)])
+    return jax.tree.map(one, stacked)
+
+
+def _pad_positions(sub_pos: np.ndarray, k: int):
+    """``sub_pos`` padded to ``k`` gather positions (pad rows read row 0)
+    and the mask of the real ones."""
+    pos = np.zeros(k, np.int32)
+    pos[:len(sub_pos)] = sub_pos
+    return pos, np.arange(k) < len(sub_pos)
+
+
+def _take_rows(leaf, pos, valid):
+    """Rows ``pos`` of ``leaf``; rows where ``valid`` is False are zero."""
+    g = leaf[pos]
+    m = valid.reshape(valid.shape + (1,) * (g.ndim - 1))
+    return jnp.where(m, g, jnp.zeros((), g.dtype))
+
+
+@jax.jit
+def _gather_padded(tree, pos, valid):
+    return jax.tree.map(lambda l: _take_rows(l, pos, valid), tree)
 
 
 def _bucket(n: int, floor: int = 1) -> int:
@@ -171,14 +207,15 @@ class CohortKernels:
         if self._mega_step is None:
             fn = self._round_step_fn
 
-            def mega(params, opt_state, batches, base_keys, rnds,
-                     mal_masks, keep_masks, use_fake):
+            def mega_round_step(params, opt_state, batches, base_keys,
+                                rnds, mal_masks, keep_masks, use_fake):
                 return jax.vmap(
                     lambda p, o, b, k, r, m, kp: fn(p, o, b, k, r, m, kp,
                                                     use_fake))(
                     params, opt_state, batches, base_keys, rnds,
                     mal_masks, keep_masks)
-            self._mega_step = jax.jit(mega, static_argnames=("use_fake",))
+            self._mega_step = jax.jit(mega_round_step,
+                                      static_argnames=("use_fake",))
         return self._mega_step
 
 
@@ -244,36 +281,49 @@ class VectorCohort:
 
     def train(self, global_params, rnd: int,
               sel_idx: Sequence[int]) -> Optional[CohortSubmissions]:
-        if self._opt_holder is not None:
-            # a megastep holds this cohort's opt state stacked on its task
-            # axis; reclaim it before stepping per-task
-            self._opt_holder.flush_opt()
-        sel = np.asarray(sel_idx)
-        part = self._participation(sel)
-        if not part.any():
-            return None
-        batches = self.batch_fn(sel, rnd)
-        # malicious rows submit random weights without training (free-
-        # riding); their opt state must not advance, nor must lazy skips'
-        mal = self.is_malicious[sel]
-        submitted, self._opt, _loss = self.kernels.round_step(
-            global_params, self._opt, batches, self.key,
-            np.uint32(self._round_counter), jnp.asarray(mal),
-            jnp.asarray(part & ~mal), use_fake=bool(mal.any()))
-        self._round_counter += 1
+        with obs.span("fl.train"):
+            if self._opt_holder is not None:
+                # a megastep holds this cohort's opt state stacked on its task
+                # axis; reclaim it before stepping per-task
+                self._opt_holder.flush_opt()
+            sel = np.asarray(sel_idx)
+            part = self._participation(sel)
+            if not part.any():
+                return None
+            batches = self.batch_fn(sel, rnd)
+            # malicious rows submit random weights without training (free-
+            # riding); their opt state must not advance, nor must lazy skips'
+            mal = self.is_malicious[sel]
+            submitted, self._opt, _loss = self.kernels.round_step(
+                global_params, self._opt, batches, self.key,
+                np.uint32(self._round_counter), jnp.asarray(mal),
+                jnp.asarray(part & ~mal), use_fake=bool(mal.any()))
+            self._round_counter += 1
+            obs.count("fl.samples", _samples(batches, part & ~mal))
 
-        if part.all():
-            sub_pos = np.argsort(sel)             # CohortSubmissions order
-            stacked = (submitted if np.array_equal(sub_pos,
-                                                   np.arange(len(sel)))
-                       else jax.tree.map(lambda l: l[sub_pos], submitted))
-        else:
-            sub_pos = np.flatnonzero(part)
-            sub_pos = sub_pos[np.argsort(sel[sub_pos])]
-            stacked = jax.tree.map(lambda l: l[sub_pos], submitted)
-        cid = self.store.put(jax.tree.map(np.asarray, stacked))
-        idxs = [int(i) for i in sel[sub_pos]]
-        return CohortSubmissions(idxs, stacked, {i: cid for i in idxs})
+            if part.all():
+                sub_pos = np.argsort(sel)             # CohortSubmissions order
+                stacked = (submitted if np.array_equal(sub_pos,
+                                                       np.arange(len(sel)))
+                           else jax.tree.map(lambda l: l[sub_pos], submitted))
+            else:
+                sub_pos = np.flatnonzero(part)
+                sub_pos = sub_pos[np.argsort(sel[sub_pos])]
+                stacked = jax.tree.map(lambda l: l[sub_pos], submitted)
+            pos, valid = _pad_positions(sub_pos, len(sel))
+            padded = _gather_padded(submitted, jnp.asarray(pos),
+                                    jnp.asarray(valid))
+            cid = self.store.put(jax.tree.map(np.asarray, stacked))
+            idxs = [int(i) for i in sel[sub_pos]]
+            return CohortSubmissions(idxs, stacked, {i: cid for i in idxs},
+                                     padded)
+
+
+def _samples(batches, trained: np.ndarray) -> int:
+    """Images trained: trainers that trained x local steps x batch (the
+    batch leaves are (trainers, steps, batch, ...))."""
+    steps, batch = jax.tree.leaves(batches)[0].shape[1:3]
+    return int(np.count_nonzero(trained)) * int(steps) * int(batch)
 
 
 @functools.lru_cache(maxsize=64)
@@ -296,12 +346,13 @@ def _stack_trees(trees):
 
 
 @jax.jit
-def _gather_sorted(tree, rows, pos):
+def _gather_sorted(tree, rows, pos, valid):
     """Row-select + per-row gather in ONE dispatch: leaves (B, K, ...)
     take rows ``rows`` then reorder each by its own index vector (the
-    per-task ``sub_pos`` sort)."""
+    per-task ``sub_pos`` sort, padded to K; rows past a task's
+    submissions are zero)."""
     return jax.tree.map(
-        lambda l: jax.vmap(lambda x, p: x[p])(l[rows], pos), tree)
+        lambda l: jax.vmap(_take_rows)(l[rows], pos, valid), tree)
 
 
 @dataclasses.dataclass
@@ -313,11 +364,10 @@ class MegaRound:
                                              # member participated)
     raw: Any                  # device tree (B, K, ...), selection order —
                               # the scoring input (B = pow2 task bucket)
-    sorted_full: Any          # device tree (Bf, K, ...) for the FULL-
-                              # participation tasks, rows in sub_pos order
-                              # (None when no task had full participation)
-    active: List[int]         # task index of raw row a (first len(active))
-    full_rows: List[int]      # task index of sorted_full row f
+    sorted: Any               # device tree (B, K, ...): row a holds active
+                              # task a's submissions in sub_pos order, then
+                              # zero rows (None when no task is active)
+    active: List[int]         # task index of raw/sorted row a
     pos: List["np.ndarray"]   # per active row: sub_pos into selection order
 
 
@@ -377,74 +427,67 @@ class MegaCohort:
 
     def train(self, params_list: Sequence[Any], rnds: Sequence[int],
               sel_list: Sequence[Sequence[int]]) -> Optional[MegaRound]:
-        cohorts = self.cohorts
-        sels = [np.asarray(s) for s in sel_list]
-        K = sels[0].size
-        assert all(s.size == K for s in sels), "mega group needs uniform K"
-        parts = [c._participation(s) for c, s in zip(cohorts, sels)]
-        active = [t for t in range(len(cohorts)) if parts[t].any()]
-        subs: List[Optional[CohortSubmissions]] = [None] * len(cohorts)
-        if not active:
-            return MegaRound(subs, None, None, [], [], [])
-        # task-axis rows: active tasks padded to the pow2 bucket by
-        # replicating row 0 (padded outputs are computed and dropped)
-        rows = active + [active[0]] * (_bucket(len(active)) - len(active))
-        batches = {t: cohorts[t].batch_fn(sels[t], rnds[t]) for t in active}
-        mal = np.stack([cohorts[t].is_malicious[sels[t]] for t in rows])
-        keep = np.stack([parts[t] & ~cohorts[t].is_malicious[sels[t]]
-                         for t in rows])
-        submitted, new_opt, _loss = self.kernels.mega_round_step()(
-            _stack_trees([params_list[t] for t in rows]),
-            self._stacked_opt(rows, active),
-            _stack_trees([batches[t] for t in rows]),
-            jnp.stack([cohorts[t].key for t in rows]),
-            jnp.asarray([cohorts[t]._round_counter for t in rows],
-                        jnp.uint32),
-            jnp.asarray(mal), jnp.asarray(keep),
-            use_fake=bool(any(mal[a].any()
-                              for a in range(len(active)))))
-        # keep the new opt stacked here; cohorts flush it back on demand.
-        # Padded rows replicate row 0's inputs, so only the active slices
-        # are authoritative — flush_opt hands back exactly those
-        self._opt_stacked, self._opt_rows = new_opt, rows
-        self._opt_active = active
-        for t in active:
-            cohorts[t]._opt_holder = self
-            cohorts[t]._round_counter += 1
-        # per-task submitted gather (the VectorCohort.train sub_pos logic)
-        pos, full_rows = [], []
-        for t in active:
-            if parts[t].all():
-                pos.append(np.argsort(sels[t]))
-                full_rows.append(t)
-            else:
-                p = np.flatnonzero(parts[t])
-                pos.append(p[np.argsort(sels[t][p])])
-        # full tasks: one vmapped sorted gather + ONE host materialization
-        sorted_full = None
-        if full_rows:
-            fa = [active.index(t) for t in full_rows]
-            fb = fa + [fa[0]] * (_bucket(len(fa)) - len(fa))
-            pos_mat = np.stack([pos[a] for a in fb])
-            sorted_full = _gather_sorted(submitted, jnp.asarray(fb),
-                                         jnp.asarray(pos_mat))
-            host = jax.device_get(sorted_full)
-            for f, t in enumerate(full_rows):
-                stacked = jax.tree.map(lambda l, f=f: l[f], host)
+        with obs.span("fl.train"):
+            cohorts = self.cohorts
+            sels = [np.asarray(s) for s in sel_list]
+            K = sels[0].size
+            assert all(s.size == K for s in sels), "mega group needs uniform K"
+            parts = [c._participation(s) for c, s in zip(cohorts, sels)]
+            active = [t for t in range(len(cohorts)) if parts[t].any()]
+            subs: List[Optional[CohortSubmissions]] = [None] * len(cohorts)
+            if not active:
+                return MegaRound(subs, None, None, [], [])
+            # task-axis rows: active tasks padded to the pow2 bucket by
+            # replicating row 0 (padded outputs are computed and dropped)
+            rows = active + [active[0]] * (_bucket(len(active)) - len(active))
+            batches = {t: cohorts[t].batch_fn(sels[t], rnds[t]) for t in active}
+            mal = np.stack([cohorts[t].is_malicious[sels[t]] for t in rows])
+            keep = np.stack([parts[t] & ~cohorts[t].is_malicious[sels[t]]
+                             for t in rows])
+            submitted, new_opt, _loss = self.kernels.mega_round_step()(
+                _stack_trees([params_list[t] for t in rows]),
+                self._stacked_opt(rows, active),
+                _stack_trees([batches[t] for t in rows]),
+                jnp.stack([cohorts[t].key for t in rows]),
+                jnp.asarray([cohorts[t]._round_counter for t in rows],
+                            jnp.uint32),
+                jnp.asarray(mal), jnp.asarray(keep),
+                use_fake=bool(any(mal[a].any()
+                                  for a in range(len(active)))))
+            obs.count("fl.samples", sum(
+                _samples(batches[t], keep[a]) for a, t in enumerate(active)))
+            # keep the new opt stacked here; cohorts flush it back on demand.
+            # Padded rows replicate row 0's inputs, so only the active slices
+            # are authoritative — flush_opt hands back exactly those
+            self._opt_stacked, self._opt_rows = new_opt, rows
+            self._opt_active = active
+            for t in active:
+                cohorts[t]._opt_holder = self
+                cohorts[t]._round_counter += 1
+            # per-task submitted order (the VectorCohort.train sub_pos logic),
+            # padded to K so one gather serves every task, ragged or not
+            pos = []
+            for t in active:
+                if parts[t].all():
+                    pos.append(np.argsort(sels[t]))
+                else:
+                    p = np.flatnonzero(parts[t])
+                    pos.append(p[np.argsort(sels[t][p])])
+            pads = [_pad_positions(p, K) for p in pos]
+            fb = list(range(len(active)))
+            fb += [0] * (len(rows) - len(fb))
+            sorted_all = _gather_sorted(
+                submitted, jnp.asarray(fb),
+                jnp.asarray(np.stack([pads[a][0] for a in fb])),
+                jnp.asarray(np.stack([pads[a][1] for a in fb])))
+            padded = _unstack_fn(len(fb))(sorted_all)
+            # ONE host materialization; each task's blob is its leading rows
+            host = jax.device_get(sorted_all)
+            for a, t in enumerate(active):
+                n = len(pos[a])
+                stacked = jax.tree.map(lambda l, a=a, n=n: l[a, :n], host)
                 cid = cohorts[t].store.put(stacked)
-                idxs = [int(i) for i in sels[t][pos[active.index(t)]]]
+                idxs = [int(i) for i in sels[t][pos[a]]]
                 subs[t] = CohortSubmissions(idxs, stacked,
-                                            {i: cid for i in idxs})
-        # ragged tasks: per-task device gather (K' differs per task)
-        for a, t in enumerate(active):
-            if subs[t] is not None:
-                continue
-            stacked = jax.tree.map(
-                np.asarray,
-                jax.tree.map(lambda l, a=a, p=pos[a]: l[a][p], submitted))
-            cid = cohorts[t].store.put(stacked)
-            idxs = [int(i) for i in sels[t][pos[a]]]
-            subs[t] = CohortSubmissions(idxs, stacked,
-                                        {i: cid for i in idxs})
-        return MegaRound(subs, submitted, sorted_full, active, full_rows,
-                         pos)
+                                            {i: cid for i in idxs}, padded[a])
+            return MegaRound(subs, submitted, sorted_all, active, pos)

@@ -15,6 +15,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.escrow import Escrow
 from repro.core.gas import DEFAULT_GAS
 from repro.core.ledger import AccessControl, Tx
@@ -228,43 +229,44 @@ class AutoDFL:
         instead of a per-tx Python object.  ``payloads``: a list of dicts
         or a zero-arg callable producing one (only materialized on the
         object path; the SoA engine drops payloads by design)."""
-        n = len(senders)
-        if n == 0:
+        if not senders:
             return
-        if self.pre_tx_hook is not None:
-            self.pre_tx_hook(self._clock)
-        target = self._target()
-        gas = DEFAULT_GAS.l1_per_call.get(fn, 30000)
-        times = self._clock + 0.01 * np.arange(1, n + 1)
-        self._clock += 0.01 * n
-        if getattr(target, "soa_native", False):
-            from repro.core.engine import TxArrays
-            # ids MUST come from the target's own namespace: _tx's submit
-            # shim registers senders there, and mixing the chain's counter
-            # into the rollup's stream would collide/misattribute ids
-            sender_ids = np.array(
-                [target.sender_id(s) for s in senders], np.int32)
-            fid = target.fns.id(fn)
-            batch = TxArrays(times, np.full(n, gas, np.int64),
-                             np.full(n, fid, np.int32), sender_ids,
-                             target.fns)
-            if self._fused is not None and self._fused.covers(target):
-                # the shard pin rides into the journaled plan — the fused
-                # loop replays task-pinned routing at record time
-                self._fused.submit(target, batch, shard=self._route_shard)
-            elif self._route_shard is not None and hasattr(target, "shards"):
-                # task-pinned shard routing (core/shards.py fabric)
-                target.submit_arrays(batch, shard=self._route_shard)
+        with obs.span("fl.emit"):
+            n = len(senders)
+            if self.pre_tx_hook is not None:
+                self.pre_tx_hook(self._clock)
+            target = self._target()
+            gas = DEFAULT_GAS.l1_per_call.get(fn, 30000)
+            times = self._clock + 0.01 * np.arange(1, n + 1)
+            self._clock += 0.01 * n
+            if getattr(target, "soa_native", False):
+                from repro.core.engine import TxArrays
+                # ids MUST come from the target's own namespace: _tx's submit
+                # shim registers senders there, and mixing the chain's counter
+                # into the rollup's stream would collide/misattribute ids
+                sender_ids = np.array(
+                    [target.sender_id(s) for s in senders], np.int32)
+                fid = target.fns.id(fn)
+                batch = TxArrays(times, np.full(n, gas, np.int64),
+                                 np.full(n, fid, np.int32), sender_ids,
+                                 target.fns)
+                if self._fused is not None and self._fused.covers(target):
+                    # the shard pin rides into the journaled plan — the fused
+                    # loop replays task-pinned routing at record time
+                    self._fused.submit(target, batch, shard=self._route_shard)
+                elif self._route_shard is not None and hasattr(target, "shards"):
+                    # task-pinned shard routing (core/shards.py fabric)
+                    target.submit_arrays(batch, shard=self._route_shard)
+                else:
+                    target.submit_arrays(batch)
             else:
-                target.submit_arrays(batch)
-        else:
-            if callable(payloads):
-                payloads = payloads()
-            for k, s in enumerate(senders):
-                target.submit(Tx(fn, s,
-                                 payloads[k] if payloads else {}, gas,
-                                 float(times[k])))
-        self.protocol_calls[fn] = self.protocol_calls.get(fn, 0) + n
+                if callable(payloads):
+                    payloads = payloads()
+                for k, s in enumerate(senders):
+                    target.submit(Tx(fn, s,
+                                     payloads[k] if payloads else {}, gas,
+                                     float(times[k])))
+            self.protocol_calls[fn] = self.protocol_calls.get(fn, 0) + n
 
     def _tx_batch_many(self, groups) -> None:
         """Megabatched emission: ``groups`` is ``[(fn, senders, shard)]``
@@ -278,55 +280,55 @@ class AutoDFL:
         bytes, fewer transfers — the megabatching win).  SoA targets only
         (payload callables are never materialized there)."""
         groups = [(fn, s, shard) for fn, s, shard in groups if s]
-        total = sum(len(s) for _, s, _ in groups)
-        if total == 0:
-            return
-        if self.pre_tx_hook is not None:
-            self.pre_tx_hook(self._clock)
-        target = self._target()
-        assert getattr(target, "soa_native", False), \
-            "_tx_batch_many needs a SoA-native target"
-        from repro.core.engine import TxArrays
-        times = np.empty(total, np.float64)
-        gas = np.empty(total, np.int64)
-        fn_id = np.empty(total, np.int32)
-        sender_id = np.empty(total, np.int32)
-        shard_of = np.full(total, -1, np.int64)
-        o = 0
-        for fn, senders, shard in groups:
-            n = len(senders)
-            # advance the clock group-by-group with _tx_batch's exact
-            # arithmetic — one flat arange over the concatenation drifts
-            # by ulps and un-pins event timestamps
-            times[o: o + n] = self._clock + 0.01 * np.arange(1, n + 1)
-            self._clock += 0.01 * n
-            gas[o: o + n] = DEFAULT_GAS.l1_per_call.get(fn, 30000)
-            fn_id[o: o + n] = target.fns.id(fn)
-            sender_id[o: o + n] = [target.sender_id(s) for s in senders]
-            if shard is not None:
-                shard_of[o: o + n] = shard
-            self.protocol_calls[fn] = self.protocol_calls.get(fn, 0) + n
-            o += n
-        fused = self._fused if (self._fused is not None
-                                and self._fused.covers(target)) else None
-        sharded = hasattr(target, "shards")
-        if sharded:
-            assert (shard_of >= 0).all(), \
-                "megabatched emission on a fabric needs per-task shard pins"
-            dests = np.unique(shard_of)
-        else:
-            dests = np.array([-1])
-        for k in dests:
-            m = shard_of == k if sharded else slice(None)
-            batch = TxArrays(times[m], gas[m], fn_id[m], sender_id[m],
-                             target.fns)
-            pin = int(k) if sharded else None
-            if fused is not None:
-                fused.submit(target, batch, shard=pin)
-            elif pin is not None:
-                target.submit_arrays(batch, shard=pin)
-            else:
-                target.submit_arrays(batch)
+        if groups:
+            with obs.span("fl.emit"):
+                total = sum(len(s) for _, s, _ in groups)
+                if self.pre_tx_hook is not None:
+                    self.pre_tx_hook(self._clock)
+                target = self._target()
+                assert getattr(target, "soa_native", False), \
+                    "_tx_batch_many needs a SoA-native target"
+                from repro.core.engine import TxArrays
+                times = np.empty(total, np.float64)
+                gas = np.empty(total, np.int64)
+                fn_id = np.empty(total, np.int32)
+                sender_id = np.empty(total, np.int32)
+                shard_of = np.full(total, -1, np.int64)
+                o = 0
+                for fn, senders, shard in groups:
+                    n = len(senders)
+                    # advance the clock group-by-group with _tx_batch's exact
+                    # arithmetic — one flat arange over the concatenation drifts
+                    # by ulps and un-pins event timestamps
+                    times[o: o + n] = self._clock + 0.01 * np.arange(1, n + 1)
+                    self._clock += 0.01 * n
+                    gas[o: o + n] = DEFAULT_GAS.l1_per_call.get(fn, 30000)
+                    fn_id[o: o + n] = target.fns.id(fn)
+                    sender_id[o: o + n] = [target.sender_id(s) for s in senders]
+                    if shard is not None:
+                        shard_of[o: o + n] = shard
+                    self.protocol_calls[fn] = self.protocol_calls.get(fn, 0) + n
+                    o += n
+                fused = self._fused if (self._fused is not None
+                                        and self._fused.covers(target)) else None
+                sharded = hasattr(target, "shards")
+                if sharded:
+                    assert (shard_of >= 0).all(), \
+                        "megabatched emission on a fabric needs per-task shard pins"
+                    dests = np.unique(shard_of)
+                else:
+                    dests = np.array([-1])
+                for k in dests:
+                    m = shard_of == k if sharded else slice(None)
+                    batch = TxArrays(times[m], gas[m], fn_id[m], sender_id[m],
+                                     target.fns)
+                    pin = int(k) if sharded else None
+                    if fused is not None:
+                        fused.submit(target, batch, shard=pin)
+                    elif pin is not None:
+                        target.submit_arrays(batch, shard=pin)
+                    else:
+                        target.submit_arrays(batch)
 
     # -- fused end-of-task settlement (step 16, Eq. 2-10) -------------------------
     def settle_window(self, runtimes) -> None:
@@ -336,36 +338,40 @@ class AutoDFL:
         and reputation txs.  Row order = runtime order (deterministic)."""
         if not runtimes:
             return
-        n = len(self.trainer_ids)
-        stack = lambda key: np.stack([getattr(rt, key) for rt in runtimes])
-        rounds_total = np.stack([np.full(n, float(rt.rounds), np.float32)
-                                 for rt in runtimes])
-        self.book, diags = end_of_multitask_update(
-            self.book, stack("score_auto"), stack("completed"), rounds_total,
-            stack("dists"), stack("participated"), self.rep_params)
-        reputations = np.asarray(self.book.reputation)
-        s_rep = np.asarray(diags["s_rep"])
-        for k, rt in enumerate(runtimes):
-            self._route_shard = getattr(rt, "shard", None)
-            try:
-                self._tx_batch(
-                    "calculateSubjectiveRep",
-                    [self.trainer_ids[i] for i in rt.sel_idx],
-                    lambda k=k, rt=rt: [{"value": float(s_rep[k, i])}
-                                        for i in rt.sel_idx])
-            finally:
-                self._route_shard = None
-            self.tsc.record_scores(rt.task_id, {
-                self.trainer_ids[i]: float(rt.score_auto[i])
-                for i in rt.sel_idx})
-            payouts = self.tsc.close_task(rt.task_id)
-            diag_k = {key: np.asarray(v[k]) for key, v in diags.items()}
-            rt.result = FLTaskResult(rt.params, rt.score_auto, reputations,
-                                     payouts, [diag_k])
-            rt.phase = "done"
-        # cross-shard reputation settlement: commit the merged book/escrow
-        # into the array state; the next window-boundary seal roots it
-        self._sync_fabric_state()
+        with obs.span("fl.settle"):
+            n = len(self.trainer_ids)
+            stack = lambda key: np.stack([getattr(rt, key) for rt in runtimes])
+            rounds_total = np.stack([np.full(n, float(rt.rounds), np.float32)
+                                     for rt in runtimes])
+            self.book, diags = end_of_multitask_update(
+                self.book, stack("score_auto"), stack("completed"), rounds_total,
+                stack("dists"), stack("participated"), self.rep_params)
+            reputations = np.asarray(self.book.reputation)
+            s_rep = np.asarray(diags["s_rep"])
+            for k, rt in enumerate(runtimes):
+                self._route_shard = getattr(rt, "shard", None)
+                try:
+                    self._tx_batch(
+                        "calculateSubjectiveRep",
+                        [self.trainer_ids[i] for i in rt.sel_idx],
+                        lambda k=k, rt=rt: [{"value": float(s_rep[k, i])}
+                                            for i in rt.sel_idx])
+                finally:
+                    self._route_shard = None
+                self.tsc.record_scores(rt.task_id, {
+                    self.trainer_ids[i]: float(rt.score_auto[i])
+                    for i in rt.sel_idx})
+                payouts = self.tsc.close_task(rt.task_id)
+                # the closed task's round models leave the node's blob store
+                # (a long-running node unpins them once the task is settled)
+                self.store.drop(self.tsc.retire_models(rt.task_id))
+                diag_k = {key: np.asarray(v[k]) for key, v in diags.items()}
+                rt.result = FLTaskResult(rt.params, rt.score_auto, reputations,
+                                         payouts, [diag_k])
+                rt.phase = "done"
+            # cross-shard reputation settlement: commit the merged book/escrow
+            # into the array state; the next window-boundary seal roots it
+            self._sync_fabric_state()
 
     # -- one full task (steps 1-16 of Fig. 1), driven sequentially ----------------
     def run_task(self, task, agents, batch_fn=None,

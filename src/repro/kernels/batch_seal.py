@@ -79,12 +79,18 @@ def batch_seal_pallas(words: np.ndarray, starts: np.ndarray, *,
     starts = np.asarray(starts, np.int64)
     nb = len(starts)
     lens = np.diff(np.concatenate([starts, [len(w)]]))
-    width = max(LANES, -(-int(lens.max()) // LANES) * LANES)
+    # rows and row width bucketed to powers of two: one compiled program
+    # per bucket, not per batch count or longest batch (extra zero rows
+    # are folded and dropped)
+    width = _pow2(int(lens.max()), LANES)
     block_w = min(width, SEAL_BLOCK_W)
-    width = -(-width // block_w) * block_w
-    tiles = np.zeros((nb, width), np.uint32)
+    tiles = np.zeros((_pow2(nb, 8), width), np.uint32)
     seg = np.repeat(np.arange(nb), lens)
     tiles[seg, np.arange(len(w)) - starts[seg]] = w
     out = _seal_pallas_call(jnp.asarray(tiles), block_w=block_w,
                             interpret=bool(interpret))
-    return np.asarray(out)
+    return np.asarray(out)[:nb]
+
+
+def _pow2(n: int, floor: int) -> int:
+    return max(floor, 1 << max(0, (int(n) - 1).bit_length()))

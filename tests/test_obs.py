@@ -7,6 +7,12 @@ the window counts itself, its kernel calls and the bytes each call
 copies to the device (the committed words once, then only what a window
 touched); the NumPy mirrors stay unwrapped and uncounted;
 the node service reports the counters under ``"node"``.
+
+One CPU-size FL epoch through ``Scheduler`` (megastep): the FL spans
+show, emission nests in settlement and in nothing else of the protocol,
+and the counters count tasks, rounds, images trained and images the
+oracles evaluated exactly; the mega score table scored in chunks equals
+the one-chunk table bit for bit, and the chunk is the largest that fits.
 """
 import asyncio
 import glob
@@ -61,7 +67,7 @@ def _window(client, n=300, seed=0):
     loop.execute()
 
 
-def _host_spans(tdir):
+def _host_spans(tdir, prefix="ledger."):
     from jax.profiler import ProfileData
     path = glob.glob(os.path.join(tdir, "**", "*.xplane.pb"),
                      recursive=True)[-1]
@@ -70,7 +76,7 @@ def _host_spans(tdir):
         if plane.name == "/host:CPU":
             for line in plane.lines:
                 out += [(e.name, e.start_ns, e.start_ns + e.duration_ns)
-                        for e in line.events if e.name.startswith("ledger.")]
+                        for e in line.events if e.name.startswith(prefix)]
     return out
 
 
@@ -160,3 +166,109 @@ def test_node_service_reports_the_counters():
     stats = asyncio.run(run())
     assert stats["node"]["windows"] == 3
     assert stats["node"] == obs.counters()
+
+
+# -- the FL protocol's spans and counters ----------------------------------------
+FL_SPANS = ("fl.select", "fl.train", "fl.score", "fl.aggregate", "fl.emit",
+            "fl.settle")
+FL_BEHAVIORS = ("good", "good", "malicious", "lazy")
+
+
+def _fl_world(n=8, steps=2, batch=4):
+    import jax.numpy as jnp
+
+    from repro.data.synthetic import gaussian_clusters
+    from repro.models.mlp import TinyMLP
+    from repro.optim.optimizers import OptimizerSpec, make_optimizer
+    model = TinyMLP(16, 8, 10)
+    opt = make_optimizer(OptimizerSpec(name="sgdm", lr=0.1, grad_clip=5.0))
+    xs, ys = gaussian_clusters(256, 16, 10, seed=1)
+    vx, vy = gaussian_clusters(40, 16, 10, seed=2)
+    val = {"x": jnp.asarray(vx), "labels": jnp.asarray(vy)}
+
+    def batch_fn(sel, rnd):
+        idx = np.random.default_rng(rnd).integers(
+            0, len(xs), (len(sel), steps, batch))
+        return {"x": jnp.asarray(xs[idx]), "labels": jnp.asarray(ys[idx])}
+    return model, opt, val, batch_fn, model.accuracy_fn()
+
+
+def _fl_epoch(n_tasks=2, rounds=2, n=8, steps=2, batch=4, on_round=None):
+    from repro.api import FLTaskSpec
+    from repro.fl.cohort import CohortKernels, VectorCohort
+    from repro.fl.dp import DPConfig
+    from repro.fl.scheduler import Scheduler
+    from repro.fl.server import AutoDFL
+    model, opt, val, batch_fn, eval_fn = _fl_world(n, steps, batch)
+    node = AutoDFL(model, opt, n, eval_fn, val,
+                   spec=NodeSpec(trainer_funds=50.0))
+    dp = DPConfig(noise_multiplier=0.05, batch_size=batch)
+    kern = CohortKernels(model, opt, dp)
+    sch = Scheduler(node, seal_every=1, on_round=on_round)
+    behaviors = [FL_BEHAVIORS[i % 4] for i in range(n)]
+    for t in range(n_tasks):
+        sch.add_task(FLTaskSpec(f"t{t}", rounds=rounds, init_seed=t),
+                     VectorCohort(model, opt, batch_fn, node.store,
+                                  behaviors=behaviors, local_steps=steps,
+                                  dp=dp, seed=t, kernels=kern))
+    sch.run()
+    return node, sch, behaviors
+
+
+def test_fl_spans_open_and_nest(tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        _, sch, _ = _fl_epoch()
+    assert sch.mega_windows > 0
+    spans = _host_spans(str(tmp_path), prefix="fl.")
+    assert set(FL_SPANS) <= {s[0] for s in spans}
+    # settlement emits calculateSubjectiveRep inside its span, the rounds
+    # emit outside it; the other layers nest in none of the FL spans
+    settles = [s for s in spans if s[0] == "fl.settle"]
+    emits = [s for s in spans if s[0] == "fl.emit"]
+    inside = [e for e in emits
+              if any(p[1] <= e[1] and e[2] <= p[2] for p in settles)]
+    assert inside and len(inside) < len(emits)
+    for inner in ("fl.select", "fl.train", "fl.score", "fl.aggregate"):
+        for outer in FL_SPANS:
+            if outer != inner:
+                assert not _inside(spans, inner, outer), (inner, outer)
+
+
+def test_fl_counters_count_exactly():
+    steps, batch, n_tasks, rounds = 2, 4, 2, 2
+    recs = []
+    node, sch, behaviors = _fl_epoch(n_tasks, rounds, steps=steps,
+                                     batch=batch, on_round=recs.append)
+    c = obs.counters()
+    assert {r.task_id for r in recs} == {rt.task_id for rt in sch.runtimes}
+    trained = sum(sum(behaviors[i] != "malicious" for i in rec.idxs)
+                  for rec in recs)
+    assert c["fl.tasks"] == n_tasks
+    assert c["fl.rounds"] == n_tasks * rounds
+    assert c["fl.samples"] == trained * steps * batch
+    assert c["fl.eval_images"] == sum(len(r.idxs) for r in recs) * 40
+
+
+@pytest.mark.parametrize("chunk", [1, 2, 4])
+def test_chunked_mega_score_table_equals_one_dispatch(chunk):
+    import jax.numpy as jnp
+
+    from repro.core.oracle import ValidationSlices, _mega_eval
+    model, _, val, _, eval_fn = _fl_world()
+    keys = jax.random.split(jax.random.key(0), 3 * 8)
+    trees = [model.init_params(k) for k in keys]
+    stacked = jax.tree.map(lambda *xs: jnp.stack(xs).reshape(
+        (3, 8) + xs[0].shape), *trees)
+    slices = ValidationSlices(val, 5)
+    whole = np.asarray(_mega_eval(eval_fn, 8)(stacked, slices.stacked))
+    parts = np.asarray(_mega_eval(eval_fn, chunk)(stacked, slices.stacked))
+    assert whole.shape == (3, 5, 8)
+    np.testing.assert_array_equal(parts, whole)
+
+
+@pytest.mark.parametrize("n,per,budget,chunk", [
+    (128, 0.25e9, 7.9e9, 16), (128, 1.0, 1e9, 128), (128, 2e9, 1e9, 1),
+    (12, 1.0, 8, 6)])
+def test_score_chunk_is_the_largest_divisor_that_fits(n, per, budget, chunk):
+    from repro.core.oracle import score_chunk
+    assert score_chunk(n, per, budget) == chunk
