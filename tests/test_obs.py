@@ -6,17 +6,19 @@ do (kernel call in commitment in seal); with the device impls selected
 the window counts itself, its kernel calls and the bytes each call
 copies to the device (the committed words once, then only what a window
 touched); the NumPy mirrors stay unwrapped and uncounted;
-the node service reports the counters under ``"node"``.
+the node service reports the counters under ``"node"``; a blob put
+counts its path (raw array tree or pickle) and the bytes it hashed.
 
 One CPU-size FL epoch through ``Scheduler`` (megastep): the FL spans
 show, emission nests in settlement and in nothing else of the protocol,
-and the counters count tasks, rounds, images trained and images the
-oracles evaluated exactly; the mega score table scored in chunks equals
+and the counters count tasks, rounds, images trained, images the
+oracles evaluated and blob puts exactly; the mega score table scored in chunks equals
 the one-chunk table bit for bit, and the chunk is the largest that fits.
 """
 import asyncio
 import glob
 import os
+import pickle
 
 import jax
 import numpy as np
@@ -27,6 +29,7 @@ from repro.api import NodeClient, NodeSpec, ServeSpec
 from repro.core.engine import FnRegistry, TxArrays
 from repro.core.fused import FusedWindowLoop
 from repro.core.state import STATE_CHUNK_WORDS
+from repro.core.storage import BlobStore
 from repro.serve import NodeService
 
 N_ACCOUNTS = 1 << 12
@@ -152,6 +155,17 @@ def test_counters_snapshot_and_reset():
     assert obs.counters() == {}
 
 
+def test_blob_puts_count_their_path_and_bytes():
+    store = BlobStore()
+    tree = {"w": np.ones((3, 4), np.float32), "b": np.zeros(5, np.int64)}
+    meta = {"arch": "lenet5"}
+    store.put(tree)
+    store.put(meta)
+    assert obs.counters() == {
+        "blob.tree_puts": 1, "blob.pickle_puts": 1,
+        "blob.hashed_bytes": 3 * 4 * 4 + 5 * 8 + len(pickle.dumps(meta))}
+
+
 def test_node_service_reports_the_counters():
     obs.count("windows", 3)
 
@@ -247,6 +261,10 @@ def test_fl_counters_count_exactly():
     assert c["fl.rounds"] == n_tasks * rounds
     assert c["fl.samples"] == trained * steps * batch
     assert c["fl.eval_images"] == sum(len(r.idxs) for r in recs) * 40
+    # each task-round's submissions are one raw array-tree put; the
+    # scheduler's model description, one pickled put a task
+    assert c["blob.tree_puts"] == len(recs)
+    assert c["blob.pickle_puts"] == n_tasks
 
 
 @pytest.mark.parametrize("chunk", [1, 2, 4])
