@@ -12,8 +12,8 @@ Phases (one process; JAX holds the chip for all of them):
     ledger op forced to its NumPy mirror.  Roots, digests, gas and events
     must be equal bit for bit.
   * fl — LeNet-5 at its published widths: 2 concurrent tasks x 8 trainers
-    (good, good, malicious, lazy, ...) through ``Scheduler`` with the
-    Pallas Eq. 1 aggregation, its kernels checked against ``kernels/ref.py``
+    (good, good, malicious, lazy, ...) through ``Scheduler``, its XLA
+    Eq. 1 merge and Eq. 4 distances checked against ``kernels/ref.py``
     and the malicious trainers ending with the lowest reputation.
   * fabric (``--chips 4`` only) — the FL scheduler over a
     ``ShardSpec(count=4, mesh="on")`` fabric, whose lane seals run
@@ -183,21 +183,30 @@ def _lenet_world():
 def fl_phase(probe: Probe) -> None:
     import numpy as np
 
+    from repro.core.aggregation import (weighted_average_tree_jit,
+                                        weighted_average_tree_mega)
+    from repro.fl.scheduler import _settle_distances
     from repro.launch.smoke import agg_errors, malicious_lowest, run_fl
     model, opt, eval_fn, val, bf = _lenet_world()
     report_impls("fl", want_device=True)
-    with Phase(probe, "fl") as ph:
+    with Phase(probe, "fl"):
         node, sch, res = run_fl(model, opt, eval_fn, val, bf,
                                 n_trainers=FL_TRAINERS, n_tasks=FL_TASKS,
                                 rounds=FL_ROUNDS)
         errs = agg_errors(sch)
-    names = {k[0] for k in ph.kernels}
-    check({"weighted_agg", "model_distance"} <= names,
-          f"FL kernels traced: {sorted(names)}")
+    # the merge is the per-task or the megastep XLA program, as the
+    # scheduler's window allows; the distances are one program
+    programs = sorted(f.__name__ for f in (weighted_average_tree_jit,
+                                           weighted_average_tree_mega,
+                                           _settle_distances)
+                      if f._cache_size())
+    say("fl", f"merge programs compiled {programs}")
+    check("_settle_distances" in programs and len(programs) > 1,
+          f"FL merge programs compiled: {programs}")
     check(set(res) == {f"task{t}" for t in range(FL_TASKS)}, "FL results")
     for e in errs:
-        say("fl", f"kernels vs ref.py {e}")
-        check(e["ok"], f"aggregation kernels outside tolerance: {e}")
+        say("fl", f"merge and distances vs ref.py {e}")
+        check(e["ok"], f"merge or distances outside tolerance: {e}")
     rep = np.asarray(node.book.reputation)
     say("fl", f"reputations {np.round(rep, 4).tolist()}")
     check(malicious_lowest(node), "malicious trainers not lowest (Fig. 3)")
@@ -227,7 +236,7 @@ def fabric_phase(probe: Probe) -> None:
     prints = {}
     for mode in ("on", "off"):
         spec = NodeSpec(shards=ShardSpec(count=4, mesh=mode),
-                        use_pallas_agg=True, trainer_funds=50.0)
+                        trainer_funds=50.0)
         with Phase(probe, f"fabric-mesh-{mode}"):
             node, sch, _ = run_fl(model, opt, eval_fn, val, bf,
                                   n_trainers=FL_TRAINERS, n_tasks=FL_TASKS,
@@ -270,8 +279,8 @@ def main() -> int:
     from repro.launch.compile_cache import enable_compile_cache
     say("setup", f"{dev.platform} {dev.device_kind} x{len(devices)}; "
         f"compile cache {enable_compile_cache()}")
-    from repro.kernels.ops import _interpret
-    check(not _interpret(), "Pallas kernels would run interpreted")
+    from repro.kernels.factory import on_tpu
+    check(on_tpu(), "the kernel factory's probe does not see the TPU")
     probe = Probe()
     if args.chips == 4:
         fabric_phase(probe)

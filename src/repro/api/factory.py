@@ -72,7 +72,7 @@ def build_stack(spec: LedgerSpec, *, fns=None, state=None
             chain, n_shards=node.shards.count, batch_size=ru.batch_size,
             gas_table=node.chain.gas_table, prove_time=prove_time,
             per_tx_time=ru.per_tx_time, n_lanes=ru.n_lanes,
-            digest_backend=ru.digest_backend, route=node.shards.route,
+            route=node.shards.route,
             state=state, interconnect=node.shards.interconnect,
             mesh=node.shards.mesh, **prover_kw))
     if node.chain.backend == "vector":
@@ -80,8 +80,7 @@ def build_stack(spec: LedgerSpec, *, fns=None, state=None
         return _sanitized(chain, VectorRollup(
             chain, batch_size=ru.batch_size, gas_table=node.chain.gas_table,
             prove_time=prove_time, per_tx_time=ru.per_tx_time,
-            n_lanes=ru.n_lanes, digest_backend=ru.digest_backend,
-            **prover_kw))
+            n_lanes=ru.n_lanes, **prover_kw))
     from repro.core.rollup import Rollup
     return _sanitized(chain, Rollup(chain, batch_size=ru.batch_size,
                                     gas_table=node.chain.gas_table,

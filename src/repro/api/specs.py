@@ -71,7 +71,6 @@ class RollupSpec:
     n_lanes: int = 1
     prove_time: float = 0.9
     per_tx_time: float = 0.14
-    digest_backend: str = "auto"        # "auto" | "pallas" | "numpy"
 
     def __post_init__(self):
         if self.n_lanes < 1:
@@ -267,7 +266,6 @@ class NodeSpec:
     trainer_funds: float = 10.0
     publisher_funds: float = 1000.0
     seed: int = 0
-    use_pallas_agg: bool = False
     workload: Optional[WorkloadSpec] = None     # background traffic
     tasks: Tuple[FLTaskSpec, ...] = ()          # declarative task set
 
@@ -281,12 +279,10 @@ class NodeSpec:
             if self.chain.backend != "vector":
                 raise ValueError("sharding needs the vector chain backend")
         if self.rollup is not None and self.chain.backend == "object":
-            # the object Rollup has no lane striping or digest routing —
-            # reject rather than silently build a single-lane rollup
+            # the object Rollup has no lane striping — reject rather than
+            # silently build a single-lane rollup
             if self.rollup.n_lanes != 1:
                 raise ValueError("n_lanes > 1 needs the vector backend")
-            if self.rollup.digest_backend != "auto":
-                raise ValueError("digest_backend is a vector-backend knob")
 
     # -- legacy flag mapping (the deprecation shim's single source) --------
     @classmethod
@@ -295,8 +291,8 @@ class NodeSpec:
                     rep_params: Optional[ReputationParams] = None,
                     don: Optional[DONConfig] = None,
                     trainer_funds: float = 10.0,
-                    publisher_funds: float = 1000.0, seed: int = 0,
-                    use_pallas_agg: bool = False) -> "NodeSpec":
+                    publisher_funds: float = 1000.0,
+                    seed: int = 0) -> "NodeSpec":
         """Map the old AutoDFL kwargs onto a NodeSpec (one release shim).
 
         The mapping is pinned against the legacy constructor path by
@@ -312,7 +308,7 @@ class NodeSpec:
                         if rep_params is not None else ReputationSpec()),
             don=(DONSpec.from_config(don) if don is not None else DONSpec()),
             trainer_funds=trainer_funds, publisher_funds=publisher_funds,
-            seed=seed, use_pallas_agg=use_pallas_agg)
+            seed=seed)
 
     def describe(self) -> Dict[str, Any]:
         """JSON-friendly summary (used by benchmarks/run.py --all)."""

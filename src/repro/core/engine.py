@@ -22,10 +22,10 @@ Semantics contract (enforced by tests/test_engine.py):
   * ``VectorRollup`` with ``n_lanes=1`` writes the same ``gas_log`` rows
     (commit / amortized verify / execute per batch) as ``rollup.Rollup``.
 
-Digests: each seal folds the batch's transaction words through the same
-xor-mix used by the Pallas ``rollup_digest`` kernel.  On TPU the merged
-buffer is routed through the kernel itself; on CPU a bit-exact NumPy mirror
-(``xor_fold_digest``) is used (equality pinned by tests/test_engine.py).
+Digests: each seal folds the batch's transaction words through the
+ledger's xor-mix (``kernels/digest.py``), by the kernel the factory picks:
+the Pallas kernels on a TPU, the bit-exact NumPy mirrors elsewhere
+(``xor_fold_digest`` is the scalar one; pinned by tests/test_engine.py).
 """
 from __future__ import annotations
 
@@ -39,29 +39,8 @@ from repro.core.events import BatchSealed, BlockPacked, EventLog
 from repro.core.gas import DEFAULT_GAS, ROLLUP_BATCH, GasTable
 from repro.core.ledger import EventHooks
 from repro.core.prover import ProverFace, ProverPipeline, session_latency
-from repro.core.state import MIX_MULT as DIGEST_MULT
-from repro.core.state import MIX_SEED as DIGEST_SEED
 from repro.core.state import Registry
-
-
-def _mix(words: np.ndarray) -> np.ndarray:
-    """THE xor-mix (bit-exact mirror of kernels.rollup_digest); every fold
-    in the repo — scalar, per-batch segments, chunked state commitment —
-    routes through this one implementation so the Pallas-pin test covers
-    all call sites (rollup.Rollup, VectorRollup.seal, core/state.py)."""
-    w = np.ascontiguousarray(words, dtype=np.uint32)
-    return (w ^ (w >> np.uint32(16))) * DIGEST_MULT
-
-
-def xor_fold_digest(words: np.ndarray) -> int:
-    """Bit-exact NumPy mirror of kernels.rollup_digest (xor-mix fold).
-
-    ``rollup_digest`` pads to a block multiple with zeros; zero words mix to
-    zero and xor-fold away, so no explicit padding is needed here.
-    """
-    if np.size(words) == 0:
-        return int(DIGEST_SEED)
-    return int(DIGEST_SEED ^ np.bitwise_xor.reduce(_mix(words)))
+from repro.kernels.digest import MIX_SEED
 
 
 def xor_fold_digest_segments(words: np.ndarray,
@@ -75,14 +54,11 @@ def xor_fold_digest_segments(words: np.ndarray,
     return get_kernel("batch_seal")(words, starts)
 
 
-def pallas_or_numpy_digest(words: np.ndarray, backend: str = "auto") -> int:
-    """Route the merged word buffer through the kernel factory (op
-    ``"rollup_digest"``): Pallas on TPU, the NumPy mirror on CPU.
-    backend: "auto" | "pallas" | "numpy" (an explicit choice maps to the
-    factory impl of the same name)."""
+def update_buffer_digest(words: np.ndarray) -> int:
+    """The merged update buffer's digest (one xor-mix fold over all of
+    it), on the kernel factory's ``"rollup_digest"`` op."""
     from repro.kernels.factory import get_kernel
-    impl = None if backend == "auto" else backend
-    return int(get_kernel("rollup_digest", impl)(words))
+    return int(get_kernel("rollup_digest")(words))
 
 
 class FnRegistry(Registry):
@@ -436,9 +412,8 @@ class VectorRollup(ProverFace, EventHooks):
     def __init__(self, l1, batch_size: int = ROLLUP_BATCH,
                  gas_table: GasTable = DEFAULT_GAS,
                  prove_time: float = 0.9, per_tx_time: float = 0.14,
-                 n_lanes: int = 1, digest_backend: str = "auto",
-                 agg_width: int = 1, prover_capacity: int = 1,
-                 finalize: str = "eager",
+                 n_lanes: int = 1, agg_width: int = 1,
+                 prover_capacity: int = 1, finalize: str = "eager",
                  prover: Optional[ProverPipeline] = None):
         assert n_lanes >= 1
         self.l1 = l1
@@ -447,7 +422,6 @@ class VectorRollup(ProverFace, EventHooks):
         self.prove_time = prove_time
         self.per_tx_time = per_tx_time
         self.n_lanes = n_lanes
-        self.digest_backend = digest_backend
         # event-log adoption + settlement-pipeline wiring (ONE copy for
         # both rollup faces — see prover.ProverFace)
         self._init_prover_face(l1, gas_table, prove_time, agg_width,
@@ -463,7 +437,7 @@ class VectorRollup(ProverFace, EventHooks):
         self.state_arrays = None
         self._state_handlers: Dict[int, Callable] = {}
         self.batch_digests: List[int] = []      # per-batch tx xor-roots
-        self.update_digest: int = int(DIGEST_SEED)  # merged-buffer digest
+        self.update_digest: int = int(MIX_SEED)  # merged-buffer digest
         self.n_batches = 0
         self._pending: List[TxArrays] = []
         self._pending_n = 0
@@ -599,8 +573,7 @@ class VectorRollup(ProverFace, EventHooks):
         roots = xor_fold_digest_segments(words, starts * 4)
         self.batch_digests.extend(int(r) for r in roots)
         # merged update-buffer digest through the kernel path
-        self.update_digest = pallas_or_numpy_digest(words,
-                                                    self.digest_backend)
+        self.update_digest = update_buffer_digest(words)
 
         first = self.n_batches
         # tx->batch provenance: map each sealed tx (arrival order == seq

@@ -15,7 +15,7 @@ ONE settlement engine all three rollup backends route through:
   2. **Session proofs** — ``close_session`` (the face's
      ``settle_session``) folds the session's batch digests into one
      session proof via the same xor-mix/chunk-fold primitive as the
-     Pallas ``rollup_digest`` kernel (``core.state.chunk_fold_digests``).
+     Pallas ``rollup_digest`` kernel (``kernels.digest.chunk_fold_digests``).
   3. **Recursive aggregation** — ``agg_width`` session proofs fold into
      one *aggregate proof* (the same construction one level up; see
      ``kernels.rollup_digest.rollup_aggregate_digests`` for the device
@@ -52,7 +52,7 @@ import numpy as np
 
 from repro.core.events import (AggregateVerified, EventLog, ProofGenerated)
 from repro.core.gas import DEFAULT_GAS, GasTable
-from repro.core.state import chunk_fold_digests
+from repro.kernels.digest import chunk_fold_digests
 
 #: finalization policies a pipeline (and repro.api.ProverSpec) accepts
 FINALIZE_MODES = ("eager", "window")

@@ -65,7 +65,7 @@ class BatchProof:
     tx_root: str
     # xor-mix fold over the batch's transaction words — same construction
     # the Pallas rollup_digest kernel computes over merged update buffers
-    # (engine.xor_fold_digest is the bit-exact CPU mirror)
+    # (kernels.digest.xor_fold_digest is the bit-exact CPU mirror)
     word_digest: int = 0
 
     def verify(self, pre_state: Dict[str, Any],
@@ -194,8 +194,9 @@ class Rollup(ObjectLedgerFace, ProverFace, EventHooks):
     def _word_digest(txs: List[Tx]) -> int:
         """Batched digest over the merged tx-word buffer — the same
         xor-mix fold the Pallas rollup_digest kernel computes (see
-        engine.xor_fold_digest for the mirror pinned against the kernel)."""
-        from repro.core.engine import TxArrays, xor_fold_digest
+        kernels.digest.xor_fold_digest, the mirror pinned against it)."""
+        from repro.core.engine import TxArrays
+        from repro.kernels.digest import xor_fold_digest
         return xor_fold_digest(TxArrays.from_txs(txs).word_buffer())
 
     def flush(self):

@@ -74,8 +74,7 @@ class ShardedRollup(EventHooks):
                  batch_size: int = ROLLUP_BATCH,
                  gas_table: GasTable = DEFAULT_GAS,
                  prove_time: float = 0.9, per_tx_time: float = 0.14,
-                 n_lanes: int = 1, digest_backend: str = "auto",
-                 route: str = "hash",
+                 n_lanes: int = 1, route: str = "hash",
                  state: Optional[StateArrays] = None,
                  agg_width: int = 1, prover_capacity: int = 1,
                  finalize: str = "eager",
@@ -103,8 +102,7 @@ class ShardedRollup(EventHooks):
         for k in range(n_shards):
             s = VectorRollup(l1, batch_size=batch_size, gas_table=gas_table,
                              prove_time=prove_time, per_tx_time=per_tx_time,
-                             n_lanes=n_lanes, digest_backend=digest_backend,
-                             prover=self.prover)
+                             n_lanes=n_lanes, prover=self.prover)
             s.fns = self.fns          # one fn namespace across the fabric
             s._event_shard = k        # shard tag on the shard's events
             s._suppress_window_event = True   # the fabric's is the window

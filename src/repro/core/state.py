@@ -15,13 +15,12 @@ could share a digest).  This module replaces it with
     + a ``TxArrays`` view (see ledger.LedgerBackend); the object path lifts
     single transactions into 1-row views.
   * a chunked Merkle-style commitment: the state's canonical u32 word
-    buffer is split into fixed-size chunks, each chunk folded with the same
-    xor-mix as the Pallas ``rollup_digest`` kernel (``chunk_fold_digests``
-    is the bit-exact NumPy mirror of ``kernels.rollup_digest.
-    rollup_chunk_digests`` — pinned by tests/test_state.py), and the chunk
-    digest vector is sealed with one sha256.  Chunking is independent of
-    the shard count, so the same transactions produce the same root no
-    matter how many shards executed them (core/shards.py).
+    buffer is split into fixed-size chunks, each chunk folded with the
+    ledger's xor-mix (``kernels/digest.py``; the kernel factory decides
+    whether the NumPy mirror or the chip folds, ``kernels/dirty_fold``),
+    and the chunk digest vector is sealed with one sha256.  Chunking is
+    independent of the shard count, so the same transactions produce the
+    same root no matter how many shards executed them (core/shards.py).
 
 Security note: like every digest in this simulator, the root is a validity
 *stand-in* for a zk proof — deterministic and tamper-evident, but not a
@@ -36,10 +35,8 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from repro import obs
-
-# Mixing constants shared with core/engine.py and kernels/rollup_digest.py.
-MIX_MULT = np.uint32(0x85EBCA6B)
-MIX_SEED = np.uint32(0x9E3779B9)
+from repro.kernels.digest import mix
+from repro.kernels.factory import get_kernel
 
 # chunk size (u32 words) of the state commitment; lane-aligned for the
 # Pallas path (kernels.rollup_digest.rollup_chunk_digests needs % 128 == 0)
@@ -85,9 +82,7 @@ def account_owner(account_ids, n_shards: int) -> np.ndarray:
     txs always execute on the shard whose partition root covers its
     account rows.  Deterministic across runs/processes (no ``hash`` salt).
     """
-    s = np.asarray(account_ids, np.uint32)
-    mixed = (s ^ (s >> np.uint32(16))) * MIX_MULT
-    return (mixed % np.uint32(n_shards)).astype(np.int64)
+    return (mix(account_ids) % np.uint32(n_shards)).astype(np.int64)
 
 
 # ---------------------------------------------------------------------------
@@ -156,69 +151,8 @@ def canonical_bytes(obj: Any) -> bytes:
 
 
 # ---------------------------------------------------------------------------
-# chunked xor-mix commitment (NumPy mirror of the Pallas chunk kernel)
+# chunked xor-mix commitment
 # ---------------------------------------------------------------------------
-_ON_TPU: Optional[bool] = None
-
-
-def tpu_digest_backend() -> bool:
-    """Whether the "auto" kernel choice should take the TPU defaults.
-
-    Probed ONCE per process: ``jax.default_backend()`` costs ~2ms per
-    call, which dominated every ``state_root()``/seal digest on the hot
-    path when probed inline (roots are per-window now — see
-    prover.ProverFace._emit_window).  The device set cannot change
-    mid-process, so caching is safe.  A failing probe raises: answering
-    "not a TPU" would quietly run the CPU mirrors on the chip.
-    """
-    global _ON_TPU
-    if _ON_TPU is None:
-        import jax
-        _ON_TPU = jax.default_backend() == "tpu"
-    return _ON_TPU
-
-
-def chunk_fold_digests(words: np.ndarray,
-                       chunk: int = STATE_CHUNK_WORDS) -> np.ndarray:
-    """Per-chunk xor-mix digests: (P,) u32 -> (ceil(P/chunk),) u32.
-
-    Bit-exact NumPy mirror of ``kernels.rollup_digest.rollup_chunk_digests``
-    (pinned by tests/test_state.py).  Zero padding folds away (zero words
-    mix to zero), matching the kernel's padded tail chunk.
-    """
-    w = np.ascontiguousarray(words, dtype=np.uint32)
-    if w.size == 0:
-        return np.array([MIX_SEED], np.uint32)
-    pad = (-w.size) % chunk
-    if pad:
-        w = np.concatenate([w, np.zeros(pad, np.uint32)])
-    mixed = (w ^ (w >> np.uint32(16))) * MIX_MULT
-    return MIX_SEED ^ np.bitwise_xor.reduce(mixed.reshape(-1, chunk), axis=1)
-
-
-def _fold_digests(words: np.ndarray, chunk: int, backend: str,
-                  resident=None) -> np.ndarray:
-    """Full per-chunk digest vector, routed by ``backend`` ("numpy" forces
-    the mirror, "pallas" forces the kernel).  "auto" follows the kernel
-    factory's choice for ``dirty_fold`` — the incremental half of the
-    same commitment — so both halves run on the same side.  On the
-    kernel path the buffer's upload seeds ``resident`` (a commit cache's
-    ``dirty_fold.Resident``), so the refolds that follow stage only the
-    words they touch."""
-    if backend == "auto":
-        from repro.kernels.factory import resolve_impl
-        backend = resolve_impl("dirty_fold")
-    if backend == "pallas" and len(words):
-        from repro.kernels.dirty_fold import upload
-        from repro.kernels.ops import _interpret
-        from repro.kernels.rollup_digest import rollup_chunk_digests
-        # a writable copy: the dirty-chunk refold patches it in place
-        return np.array(rollup_chunk_digests(
-            upload(words, chunk, resident).reshape(-1), chunk_p=chunk,
-            interpret=_interpret()))
-    return chunk_fold_digests(words, chunk)
-
-
 def _seal_digests(header: bytes, n_words: int, digests: np.ndarray) -> str:
     """One sha256 over the chunk digest vector + schema/length header."""
     h = hashlib.sha256()
@@ -229,38 +163,25 @@ def _seal_digests(header: bytes, n_words: int, digests: np.ndarray) -> str:
 
 
 def chunked_root(words: np.ndarray, chunk: int = STATE_CHUNK_WORDS,
-                 backend: str = "auto", header: bytes = b"") -> str:
-    """Two-level commitment: per-chunk xor-mix digests (Pallas kernel on
-    TPU, NumPy mirror elsewhere), sealed with one sha256 over the chunk
-    digest vector + a schema/length header.  Returns a 32-hex root."""
-    return _seal_digests(header, len(words), _fold_digests(words, chunk,
-                                                           backend))
-
-
-def _dirty_impl(backend: str) -> Optional[str]:
-    """Map a digest-backend name onto a ``dirty_fold`` factory impl key
-    (``None`` lets the factory's own auto/env selection decide)."""
-    return backend if backend in ("numpy", "pallas") else None
+                 header: bytes = b"") -> str:
+    """Two-level commitment: per-chunk xor-mix digests (on the side the
+    kernel factory picks), sealed with one sha256 over the chunk digest
+    vector + a schema/length header.  Returns a 32-hex root."""
+    from repro.kernels.dirty_fold import fold_all
+    return _seal_digests(header, len(words), fold_all(words, chunk))
 
 
 def _refold(words: np.ndarray, touched: np.ndarray, digests: np.ndarray,
-            resident, chunk: int, backend: str) -> None:
+            resident, chunk: int) -> None:
     """Refold the chunks covering the ``touched`` word indices of a
-    commit cache's patched ``words`` into its ``digests``, in place.  A
-    device impl gets the cache's ``resident`` holder and the touched
-    indices, so it stages those words and not the buffer; the mirror
-    folds on the host, and the device copy it leaves behind is dropped,
-    to be uploaded afresh by the next device call."""
-    from repro.kernels.factory import get_kernel, resolve_impl
+    commit cache's patched ``words`` into its ``digests``, in place.  The
+    cache's ``resident`` holder and the touched indices go to the
+    factory's ``dirty_fold``: the device impl stages those words and not
+    the buffer."""
     dirty = np.unique(touched // chunk)
-    impl = resolve_impl("dirty_fold", _dirty_impl(backend))
-    fold = get_kernel("dirty_fold", impl)
-    if impl == "numpy":
-        resident.lanes = None
-        digests[dirty] = fold(words, dirty, chunk)
-    else:
-        digests[dirty] = fold(words, dirty, chunk, resident=resident,
-                              touched=touched)
+    digests[dirty] = get_kernel("dirty_fold")(words, dirty, chunk,
+                                              resident=resident,
+                                              touched=touched)
 
 
 # ---------------------------------------------------------------------------
@@ -371,8 +292,7 @@ class StateArrays:
         return ";".join(f"{name}:{np.dtype(dt).str}"
                         for name, dt in STATE_SCHEMA).encode()
 
-    def root(self, chunk: int = STATE_CHUNK_WORDS,
-             backend: str = "auto") -> str:
+    def root(self, chunk: int = STATE_CHUNK_WORDS) -> str:
         """Chunked Merkle-style state root (shard-count independent).
 
         With dirty tracking enabled the word buffer and per-chunk digest
@@ -384,15 +304,14 @@ class StateArrays:
         Pinned equal to the full refold by tests/test_state.py."""
         with obs.span("ledger.commit"):
             if not self._track_dirty:
-                return chunked_root(self.word_buffer(), chunk, backend,
+                return chunked_root(self.word_buffer(), chunk,
                                     header=self.schema_header())
             cache = self._commit_caches.get(("flat", chunk))
             if cache is None:
-                from repro.kernels.dirty_fold import Resident
+                from repro.kernels.dirty_fold import Resident, fold_all
                 words, resident = self.word_buffer(), Resident()
                 cache = {"words": words, "resident": resident,
-                         "digests": _fold_digests(words, chunk, backend,
-                                                  resident),
+                         "digests": fold_all(words, chunk, resident),
                          "pending": []}
                 self._commit_caches[("flat", chunk)] = cache
             elif cache["pending"]:
@@ -403,7 +322,7 @@ class StateArrays:
                     touched = self._patch_rows(cache["words"], self.n,
                                                rows, rows)
                     _refold(cache["words"], touched, cache["digests"],
-                            cache["resident"], chunk, backend)
+                            cache["resident"], chunk)
             return _seal_digests(self.schema_header(), cache["words"].size,
                                  cache["digests"])
 
@@ -442,8 +361,7 @@ class StateArrays:
         return blob.view(np.uint32)
 
     def partition_roots(self, n_shards: int,
-                        chunk: int = STATE_CHUNK_WORDS,
-                        backend: str = "auto") -> List[str]:
+                        chunk: int = STATE_CHUNK_WORDS) -> List[str]:
         """All K per-shard roots in ONE ``account_owner`` pass.  Ownership
         is the same partition function hash routing uses — the shard that
         sequenced an account's txs is the shard whose root commits it.
@@ -462,10 +380,10 @@ class StateArrays:
                 owner = account_owner(np.arange(self.n), n_shards)
                 return [chunked_root(
                     self._rows_words(np.flatnonzero(owner == k)),
-                    chunk, backend, headers[k]) for k in range(n_shards)]
+                    chunk, headers[k]) for k in range(n_shards)]
             cache = self._commit_caches.get(("part", n_shards, chunk))
             if cache is None:
-                from repro.kernels.dirty_fold import Resident
+                from repro.kernels.dirty_fold import Resident, fold_all
                 owner = account_owner(np.arange(self.n), n_shards)
                 rows_k = [np.flatnonzero(owner == k)
                           for k in range(n_shards)]
@@ -473,7 +391,7 @@ class StateArrays:
                 res_k = [Resident() for _ in range(n_shards)]
                 cache = {"rows": rows_k, "words": words_k,
                          "resident": res_k,
-                         "digests": [_fold_digests(w, chunk, backend, r)
+                         "digests": [fold_all(w, chunk, r)
                                      for w, r in zip(words_k, res_k)],
                          "pending": []}
                 self._commit_caches[("part", n_shards, chunk)] = cache
@@ -493,24 +411,22 @@ class StateArrays:
                                                    shard_rows.size, rk, pos)
                         _refold(cache["words"][k], touched,
                                 cache["digests"][k], cache["resident"][k],
-                                chunk, backend)
+                                chunk)
             return [_seal_digests(headers[k], cache["words"][k].size,
                                   cache["digests"][k])
                     for k in range(n_shards)]
 
     def partition_root(self, shard: int, n_shards: int,
-                       chunk: int = STATE_CHUNK_WORDS,
-                       backend: str = "auto") -> str:
+                       chunk: int = STATE_CHUNK_WORDS) -> str:
         """Single-shard form of ``partition_roots`` — folds ONLY the
         requested shard's rows (the K-root loop the old form paid for one
         answer), unless a tracked cache already amortizes all K."""
         if self._track_dirty and ("part", n_shards,
                                   chunk) in self._commit_caches:
-            return self.partition_roots(n_shards, chunk, backend)[shard]
+            return self.partition_roots(n_shards, chunk)[shard]
         owner = account_owner(np.arange(self.n), n_shards)
         return chunked_root(
             self._rows_words(np.flatnonzero(owner == shard)), chunk,
-            backend,
             self.schema_header() + f"|shard={shard}/{n_shards}".encode())
 
     def copy(self) -> "StateArrays":

@@ -65,15 +65,15 @@ def digest_tree(tree):
     (chunked mix + wraparound-sum fold — cheap, fused, order-deterministic;
     sum instead of xor because XLA:CPU cannot lower u32-xor reductions under
     SPMD — the Pallas kernel (kernels/rollup_digest.py) keeps the xor form
-    for TPU runs).  Mixing constants are shared with the kernel and the
-    vector engine's CPU mirror (core/engine.py)."""
-    from repro.core.engine import DIGEST_MULT, DIGEST_SEED
-    acc = jnp.uint32(DIGEST_SEED)
+    for TPU runs).  Mixing constants are the ledger digest's
+    (kernels/digest.py)."""
+    from repro.kernels.digest import MIX_SEED
+    from repro.kernels.rollup_digest import mix
+    acc = jnp.uint32(MIX_SEED)
     for leaf in jax.tree.leaves(tree):
         bits = jax.lax.bitcast_convert_type(
             leaf.astype(jnp.float32).reshape(-1), jnp.uint32)
-        mixed = jnp.bitwise_xor(bits, bits >> 16) * jnp.uint32(DIGEST_MULT)
-        acc = acc + jnp.sum(mixed, dtype=jnp.uint32)
+        acc = acc + jnp.sum(mix(bits), dtype=jnp.uint32)
     return acc
 
 

@@ -206,8 +206,7 @@ class TaskRuntime:
         k = len(self.sel_idx)
         with obs.span("fl.aggregate"):
             self.params = weighted_average_tree_jit(
-                _padded(subs, k), _padded_scores(scores_np, k),
-                use_pallas=node.use_pallas_agg)
+                _padded(subs, k), _padded_scores(scores_np, k))
         node.tsc.advance_round(self.task_id)
         self.last_subs = subs
         self.last_scores = scores_np
@@ -458,10 +457,9 @@ class Scheduler:
             rts[t].last_scores = scores[a, :len(subs.idxs)].copy()
             tables_out.append(report["table"])
         # every active task merges over K rows (zero rows and zero weights
-        # past its submitters): ONE vmapped Eq. 1 dispatch.  The Pallas agg
-        # kernel is not vmap-audited — per-task covers it, same rows.
+        # past its submitters): ONE vmapped Eq. 1 dispatch
         with obs.span("fl.aggregate"):
-            if mega.active and not node.use_pallas_agg:
+            if mega.active:
                 n_rows = int(jax.tree.leaves(mega.sorted)[0].shape[0])
                 smat = np.concatenate(
                     [scores, np.repeat(scores[:1], n_rows - len(scores), 0)])
@@ -469,11 +467,6 @@ class Scheduler:
                     mega.sorted, jnp.asarray(smat)))
                 for a, t in enumerate(mega.active):
                     rts[t].params = newp[a]
-            else:
-                for a, t in enumerate(mega.active):
-                    rts[t].params = weighted_average_tree_jit(
-                        mega.subs[t].padded, jnp.asarray(scores[a]),
-                        use_pallas=node.use_pallas_agg)
         for a, t in enumerate(mega.active):
             rt, subs = rts[t], mega.subs[t]
             node.tsc.advance_round(rt.task_id)

@@ -58,7 +58,6 @@ class AutoDFL:
                  rep_params: Optional[ReputationParams] = None,
                  don: Optional[DONConfig] = None,
                  use_rollup: Optional[bool] = None,
-                 use_pallas_agg: Optional[bool] = None,
                  seed: Optional[int] = None,
                  engine: Optional[str] = None,
                  trainer_funds: Optional[float] = None,
@@ -87,14 +86,13 @@ class AutoDFL:
                     DeprecationWarning, stacklevel=2)
             spec = NodeSpec.from_legacy(
                 rep_params=rep_params, don=don, seed=seed or 0,
-                use_pallas_agg=bool(use_pallas_agg),
                 **{**self._LEGACY_DEFAULTS, **legacy})
         else:
             # spec wins wholesale — reject every kwarg it would shadow so
             # nothing is silently dropped in a mixed call (ValueError, not
             # assert: the guard must survive python -O)
             if legacy or rep_params is not None or don is not None \
-                    or use_pallas_agg is not None or seed is not None:
+                    or seed is not None:
                 raise ValueError(
                     "pass either spec= or legacy kwargs, not both")
             if spec.n_trainers not in (None, n_trainers):
@@ -113,7 +111,6 @@ class AutoDFL:
         trainer_funds = spec.trainer_funds
         publisher_funds = spec.publisher_funds
         self.val_slices = ValidationSlices(val_batch, self.don.n_oracles)
-        self.use_pallas_agg = spec.use_pallas_agg
 
         self.store = BlobStore()
         self.acl = AccessControl(["admin0", "admin1", "admin2"])

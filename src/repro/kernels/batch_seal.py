@@ -1,23 +1,14 @@
 """Batch-seal kernel: per-batch xor-mix digests over a sealed tx stream.
 
 ``VectorRollup.seal`` folds the lane-sorted word buffer into one digest
-per batch — ``[starts[i], starts[i+1])`` word segments through THE
-xor-mix (core/engine._mix / kernels.rollup_digest).  This module is the
-dedicated kernel for that inner fold, in three interchangeable impls
-(kernels/factory.py op ``"batch_seal"``):
-
-  * ``batch_seal_np`` — the bit-exact NumPy mirror (``reduceat``), and
-    the implementation behind ``engine.xor_fold_digest_segments``.
-  * ``batch_seal_jax`` — one jitted prefix-xor scan; segment digests are
-    prefix differences (xor is its own inverse).
-  * ``batch_seal_pallas`` — segments scattered into a zero-padded
-    (n_batches, width) tile (zero words mix to zero and fold away, the
-    same padding contract as ``rollup_chunk_digests``), then one Pallas
-    grid pass folds 8 batch rows per step (``rollup_digest.
-    row_fold_call``, the layout every ledger fold shares).
-
-All three return identical u32 digests for every segmentation (pinned
-by tests/test_kernels.py on the {x64 on/off} CPU matrix in CI).
+per batch: ``[starts[i], starts[i+1])`` word segments through the
+xor-mix of ``kernels/digest.py``.  This module is the device impl of
+that fold (kernels/factory.py op ``"batch_seal"``; its NumPy mirror is
+``digest.batch_seal_np``): the segments are scattered into a
+zero-padded (n_batches, width) tile (zero words mix to zero and fold
+away), then one Pallas grid pass folds 8 batch rows per step
+(``rollup_digest.row_fold_call``, the layout every ledger fold shares).
+Pinned bit-exact against the mirror by tests/test_kernels.py.
 """
 from __future__ import annotations
 
@@ -27,33 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.state import MIX_MULT, MIX_SEED
 from repro.kernels.rollup_digest import LANES, row_fold_call
-
-
-def batch_seal_np(words: np.ndarray, starts: np.ndarray) -> np.ndarray:
-    """NumPy mirror: one digest per ``[starts[i], starts[i+1])`` word
-    segment.  Segments must be non-empty (seal batches always are)."""
-    w = np.ascontiguousarray(words, np.uint32)
-    mixed = (w ^ (w >> np.uint32(16))) * MIX_MULT
-    return MIX_SEED ^ np.bitwise_xor.reduceat(mixed, starts)
-
-
-@jax.jit
-def _seal_prefix(words, starts):
-    mixed = (words ^ (words >> jnp.uint32(16))) * jnp.uint32(0x85EBCA6B)
-    prefix = jax.lax.associative_scan(jnp.bitwise_xor, mixed)
-    ends = jnp.concatenate([starts[1:], jnp.asarray(
-        [words.shape[0]], starts.dtype)])
-    lead = jnp.where(starts > 0, prefix[jnp.maximum(starts - 1, 0)],
-                     jnp.uint32(0))
-    return jnp.uint32(0x9E3779B9) ^ (prefix[ends - 1] ^ lead)
-
-
-def batch_seal_jax(words: np.ndarray, starts: np.ndarray) -> np.ndarray:
-    """XLA impl: one prefix-xor scan, segment digests by prefix xor."""
-    return np.asarray(_seal_prefix(jnp.asarray(words, jnp.uint32),
-                                   jnp.asarray(starts, jnp.int32)))
 
 
 #: longest row block (u32 words) one batch_seal grid step loads; longer

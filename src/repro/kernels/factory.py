@@ -1,4 +1,5 @@
-"""Swappable kernel factory: one registry for every ledger hot-path op.
+"""Swappable kernel factory: one registry for every ledger hot-path op,
+and the one place that decides mirror or chip.
 
 xformers-``block_factory`` shape: each op registers its interchangeable
 implementations under string keys, and call sites ask the factory
@@ -7,13 +8,13 @@ instead of hard-wiring one backend:
     from repro.kernels.factory import get_kernel
     stops = get_kernel("block_pack")(tmax, gcum, times, n_vis, limit, p0)
 
-Impl keys (per-op subsets of):
-
-  * ``"numpy"``  — the bit-exact NumPy mirror.  This is also the object
-    path's semantics: the per-tx Python engines (core/ledger.py,
-    core/rollup.py) are pinned equal to the mirrors by tests.
-  * ``"jax"``    — jitted XLA program (scan / prefix-scan forms).
-  * ``"pallas"`` — the Pallas TPU kernel (``interpret=True`` off-TPU).
+Each op registers its NumPy mirror (``"numpy"``, the semantics of
+record; the digest ops' mirrors live in ``kernels/digest.py``) and the
+device impls a backend selects: ``"jax"`` (a jitted XLA program),
+``"pallas"`` (the Pallas TPU kernel, interpreted off the chip) or
+``"shard_map"`` (the fabric's lane fold over a device mesh).  Each
+registration names the op's CPU and TPU default: the ``"auto"`` table
+(docs/KERNELS.md), read by the one backend probe, ``on_tpu()``.
 
 Every impl but the NumPy mirror stages its NumPy inputs on the device,
 so ``register_kernel`` wraps it: each call runs inside a
@@ -25,14 +26,14 @@ its resident word buffer) registers with ``counts_h2d=True`` and counts
 the bytes it actually stages itself.  The mirrors stay unwrapped.
 
 Selection: an explicit ``impl=`` wins; else the ``REPRO_KERNEL_IMPL``
-env var; else ``"auto"`` — the op's registered TPU default on a TPU
-backend, its CPU default otherwise.  Every impl of an op takes and
-returns host NumPy values with identical semantics (bit-exact, pinned
-by tests/test_kernels.py), so swapping is a pure performance choice.
+env var (tests and ``chip_smoke.py`` force the mirror with it); else
+``"auto"``.  Every impl of an op takes and returns
+host NumPy values with identical semantics (bit-exact, pinned by
+tests/test_kernels.py), so swapping is a pure performance choice.
 
-Adding a kernel: implement the mirrors in ``kernels/<op>.py``, register
-them here in ``_load()``, and pin all impls equal in
-tests/test_kernels.py — see docs/KERNELS.md.
+Adding a kernel: the mirror in ``kernels/digest.py`` (or the op's own
+module), one device impl, and a row in ``_load()`` — see
+docs/KERNELS.md.
 """
 from __future__ import annotations
 
@@ -50,6 +51,21 @@ IMPL_ENV = "REPRO_KERNEL_IMPL"
 _REGISTRY: Dict[str, Dict[str, Callable]] = {}
 _DEFAULTS: Dict[str, Dict[str, str]] = {}      # op -> {"cpu": .., "tpu": ..}
 _LOADED = False
+_ON_TPU: bool | None = None
+
+
+def on_tpu() -> bool:
+    """Whether JAX's default backend is a TPU: the one probe of the
+    backend, behind every ``"auto"`` choice and every Pallas wrapper's
+    ``interpret`` default (``kernels.ops._interpret``).  Asked once per
+    process; the device set cannot change mid-process.  A failing probe
+    raises: answering "not a TPU" would quietly run the mirrors on the
+    chip."""
+    global _ON_TPU
+    if _ON_TPU is None:
+        import jax
+        _ON_TPU = jax.default_backend() == "tpu"
+    return _ON_TPU
 
 
 def register_kernel(op: str, impl: str, fn: Callable, *,
@@ -92,6 +108,9 @@ def _load() -> None:
     _LOADED = True
     from repro.kernels import batch_seal as bs
     from repro.kernels import block_pack as bp
+    from repro.kernels import digest as dg
+    from repro.kernels import dirty_fold as df
+    from repro.kernels import shard_lanes as sl
 
     # multi-block FIFO packing (core/fused.py window loop)
     register_kernel("block_pack", "numpy", bp.block_pack_np)
@@ -99,9 +118,8 @@ def _load() -> None:
                     cpu_default=True, tpu_default=True)
 
     # per-batch seal digests (VectorRollup.seal segment fold)
-    register_kernel("batch_seal", "numpy", bs.batch_seal_np,
+    register_kernel("batch_seal", "numpy", dg.batch_seal_np,
                     cpu_default=True)
-    register_kernel("batch_seal", "jax", bs.batch_seal_jax)
     register_kernel("batch_seal", "pallas", bs.batch_seal_pallas,
                     tpu_default=True)
 
@@ -109,47 +127,30 @@ def _load() -> None:
     # fabric: every lane's per-batch roots / per-window update digests
     # fold in one call; "shard_map" runs the lanes over the 1-D "shard"
     # device mesh)
-    from repro.kernels import shard_lanes as sl
-    register_kernel("shard_seal", "numpy", sl.shard_seal_np,
+    register_kernel("shard_seal", "numpy", dg.shard_seal_np,
                     cpu_default=True)
     register_kernel("shard_seal", "jax", sl.shard_seal_jax,
                     tpu_default=True)
     register_kernel("shard_seal", "shard_map", sl.shard_seal_shard_map)
 
     # merged update-buffer digest (seal commitment; scalar u32 out)
-    def _digest_np(words):
-        from repro.core.engine import xor_fold_digest
-        return xor_fold_digest(words)
-
     def _digest_pallas(words):
         import jax.numpy as jnp
 
-        import numpy as np
         from repro.kernels.ops import rollup_digest
         return int(rollup_digest(jnp.asarray(
             np.ascontiguousarray(words, np.uint32))))
 
-    def _digest_jax(words):
-        import jax.numpy as jnp
-
-        import numpy as np
-        from repro.kernels.rollup_digest import rollup_digest_jax
-        return int(rollup_digest_jax(jnp.asarray(
-            np.ascontiguousarray(words, np.uint32))))
-
-    register_kernel("rollup_digest", "numpy", _digest_np, cpu_default=True)
-    register_kernel("rollup_digest", "jax", _digest_jax)
+    register_kernel("rollup_digest", "numpy", dg.xor_fold_digest,
+                    cpu_default=True)
     register_kernel("rollup_digest", "pallas", _digest_pallas,
                     tpu_default=True)
 
     # dirty-chunk refold (StateArrays incremental commitment): digests of
     # only the chunks a window touched, patched into the cached vector;
-    # the device impls patch a resident copy of the word buffer
-    from repro.kernels import dirty_fold as df
-    register_kernel("dirty_fold", "numpy", df.dirty_fold_np,
+    # the device impl patches a resident copy of the word buffer
+    register_kernel("dirty_fold", "numpy", dg.dirty_fold_np,
                     cpu_default=True)
-    register_kernel("dirty_fold", "jax", df.dirty_fold_jax,
-                    counts_h2d=True)
     register_kernel("dirty_fold", "pallas", df.dirty_fold_pallas,
                     tpu_default=True, counts_h2d=True)
 
@@ -173,8 +174,7 @@ def resolve_impl(op: str, impl: str | None = None) -> str:
                        f"registered: {sorted(_REGISTRY)}")
     choice = impl or os.environ.get(IMPL_ENV) or "auto"
     if choice == "auto":
-        from repro.core.state import tpu_digest_backend
-        choice = _DEFAULTS[op]["tpu" if tpu_digest_backend() else "cpu"]
+        choice = _DEFAULTS[op]["tpu" if on_tpu() else "cpu"]
     return choice
 
 

@@ -4,6 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.digest import MIX_MULT, MIX_SEED
+
 
 def weighted_agg_ref(stacked: jnp.ndarray, scores: jnp.ndarray) -> jnp.ndarray:
     """Eq. 1: (n, P), (n,) -> (P,) score-weighted average, f32 accumulation."""
@@ -43,6 +45,6 @@ def gmm_ref(xe: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
 
 def rollup_digest_ref(buf_u32: jnp.ndarray) -> jnp.ndarray:
     """Chunked XOR-mix fold over a u32 buffer -> scalar u32."""
-    mixed = jnp.bitwise_xor(buf_u32, buf_u32 >> 16) * jnp.uint32(0x85EBCA6B)
-    out = jnp.uint32(0x9E3779B9)
+    mixed = jnp.bitwise_xor(buf_u32, buf_u32 >> 16) * jnp.uint32(MIX_MULT)
+    out = jnp.uint32(MIX_SEED)
     return out ^ jax.lax.reduce(mixed, jnp.uint32(0), jnp.bitwise_xor, (0,))

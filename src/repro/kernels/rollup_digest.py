@@ -12,24 +12,29 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.digest import MIX_MULT, MIX_SEED
 
 LANES = 128
 SUBLANES = 8
-_MIX_MULT = 0x85EBCA6B
-_MIX_SEED = 0x9E3779B9
 
 
-def _mix(x):
-    return jnp.bitwise_xor(x, x >> 16) * jnp.uint32(_MIX_MULT)
+def mix(x):
+    """``kernels.digest.mix`` on u32 device arrays (and in kernels)."""
+    return jnp.bitwise_xor(x, x >> 16) * jnp.uint32(MIX_MULT)
+
+
+def seed(fold):
+    """A xor fold of mixed words -> its digest."""
+    return jnp.uint32(MIX_SEED) ^ fold
 
 
 def _fold_lane_tiles(x_ref):
     """xor of the ``(8, 128)`` lane tiles of a ``(8, W)`` block ->
     ``(8, 128)``.  Static lane-aligned slices only: Mosaic has no
     ``reduce`` lowering for xor, and whole-tile xors stay on the VPU."""
-    acc = _mix(x_ref[:, pl.ds(0, LANES)])
+    acc = mix(x_ref[:, pl.ds(0, LANES)])
     for j in range(1, x_ref.shape[1] // LANES):
-        acc = acc ^ _mix(x_ref[:, pl.ds(j * LANES, LANES)])
+        acc = acc ^ mix(x_ref[:, pl.ds(j * LANES, LANES)])
     return acc
 
 
@@ -75,8 +80,8 @@ def row_fold_call(rows2d: jnp.ndarray, *, name: str, interpret: bool,
         name=name,
     )(rows2d)
     # the last 128-lane fold + seed runs in XLA on the small partials
-    return (jnp.uint32(_MIX_SEED) ^ jax.lax.reduce(
-        out, jnp.uint32(0), jnp.bitwise_xor, (1,)))[:r]
+    return seed(jax.lax.reduce(out, jnp.uint32(0), jnp.bitwise_xor,
+                               (1,)))[:r]
 
 
 def _digest_kernel(x_ref, o_ref):
@@ -87,7 +92,7 @@ def _digest_kernel(x_ref, o_ref):
     # fold the block's (8, 128) row tiles into the resident out tile
     acc = o_ref[...]
     for j in range(x_ref.shape[0] // SUBLANES):
-        acc = acc ^ _mix(x_ref[pl.ds(j * SUBLANES, SUBLANES), :])
+        acc = acc ^ mix(x_ref[pl.ds(j * SUBLANES, SUBLANES), :])
     o_ref[...] = acc
 
 
@@ -100,7 +105,8 @@ def rollup_digest(buf: jnp.ndarray, block_p: int = 16384,
     if buf.dtype != jnp.uint32:
         buf = jax.lax.bitcast_convert_type(buf.astype(jnp.float32), jnp.uint32)
     P = buf.shape[0]
-    pad = (-P) % block_p
+    # an empty buffer folds one zero block: the bare seed, as the mirror
+    pad = (-P) % block_p if P else block_p
     if pad:
         buf = jnp.pad(buf, (0, pad))
     rows = (P + pad) // LANES
@@ -117,23 +123,8 @@ def rollup_digest(buf: jnp.ndarray, block_p: int = 16384,
     )(buf.reshape(rows, LANES))
     # final (8, 128) fold + seed in XLA (tiny); seeding here keeps the
     # lane-broadcast in the kernel from cancelling it (even lane count)
-    return jnp.uint32(_MIX_SEED) ^ jax.lax.reduce(
-        out.reshape(-1), jnp.uint32(0), jnp.bitwise_xor, (0,))
-
-
-@jax.jit
-def rollup_digest_jax(buf: jnp.ndarray) -> jnp.ndarray:
-    """Pure-jnp VPU form of ``rollup_digest`` (no pallas_call): the
-    device-portable middle impl the kernel factory registers as
-    ``("rollup_digest", "jax")``.  Bit-exact with the NumPy mirror
-    ``core.engine.xor_fold_digest`` (semantics-of-record) and the Pallas
-    form above — pinned by tests/test_kernels.py.  An empty buffer folds
-    to the bare seed, matching the mirror."""
-    if buf.dtype != jnp.uint32:
-        buf = jax.lax.bitcast_convert_type(buf.astype(jnp.float32), jnp.uint32)
-    mixed = jnp.bitwise_xor(buf, buf >> 16) * jnp.uint32(0x85EBCA6B)
-    return jnp.uint32(0x9E3779B9) ^ jax.lax.reduce(
-        mixed, jnp.uint32(0), jnp.bitwise_xor, (0,))
+    return seed(jax.lax.reduce(out.reshape(-1), jnp.uint32(0),
+                               jnp.bitwise_xor, (0,)))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk_p", "interpret"))
@@ -143,7 +134,7 @@ def rollup_chunk_digests(buf: jnp.ndarray, chunk_p: int = 2048,
 
     buf: (P,) float32/uint32 buffer -> (ceil(P/chunk_p),) u32, one xor-mix
     fold per ``chunk_p``-word chunk (zero-padded tail; zero words fold
-    away).  ``core.state.chunk_fold_digests`` is the bit-exact NumPy
+    away).  ``kernels.digest.chunk_fold_digests`` is the bit-exact NumPy
     mirror, pinned by tests/test_state.py.  chunk_p must be lane-aligned
     (% 128) so each chunk maps to whole VPU rows.
     """
@@ -169,7 +160,7 @@ def rollup_aggregate_digests(digests: jnp.ndarray,
     SAME xor-mix fold the batch digests were built with, one level up:
     batch tx words -> batch digest -> session proof -> aggregate proof.
     The digest vector is tiny (one word per proof), so this is a plain
-    jitted VPU fold rather than a pallas_call; ``core.state.
+    jitted VPU fold rather than a pallas_call; ``kernels.digest.
     chunk_fold_digests(digests, chunk=width)`` is the bit-exact NumPy
     mirror (pinned by tests/test_prover.py).  Zero padding folds away
     (zero words mix to zero), matching the chunk kernel's padded tail.
@@ -178,7 +169,5 @@ def rollup_aggregate_digests(digests: jnp.ndarray,
     pad = (-d.shape[0]) % width
     if pad:
         d = jnp.pad(d, (0, pad))
-    d2 = d.reshape(-1, width)
-    mixed = jnp.bitwise_xor(d2, d2 >> 16) * jnp.uint32(0x85EBCA6B)
-    return jnp.uint32(0x9E3779B9) ^ jax.lax.reduce(
-        mixed, jnp.uint32(0), jnp.bitwise_xor, (1,))
+    return seed(jax.lax.reduce(mix(d.reshape(-1, width)), jnp.uint32(0),
+                               jnp.bitwise_xor, (1,)))

@@ -8,11 +8,11 @@ multi-lane kernel (kernels/factory.py op ``"shard_seal"``): the K lanes
 become the rows of one ``(K, W)`` SoA word grid, and ONE call folds
 every lane's segments:
 
-  * ``shard_seal_np``        — the bit-exact NumPy mirror (per-row
+  * ``digest.shard_seal_np`` — the bit-exact NumPy mirror (per-row
     ``reduceat``, THE semantics);
   * ``shard_seal_jax``       — one jitted program: a 2-D prefix-xor
     ``associative_scan`` over the row axis-1, segment digests by prefix
-    difference (the ``batch_seal_jax`` form, vectorized over lanes);
+    difference;
   * ``shard_seal_shard_map`` — the same fold ``shard_map``-ped over a
     1-D ``"shard"`` mesh axis (launch/mesh.make_shard_mesh +
     sharding/specs.shard_lane_spec): each device owns a contiguous row
@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.state import MIX_MULT, MIX_SEED
+from repro.kernels.rollup_digest import mix, seed
 
 
 def _pow2(n: int, floor: int) -> int:
@@ -55,33 +55,12 @@ def _pow2(n: int, floor: int) -> int:
     return 1 << max(n - 1, floor - 1, 1).bit_length()
 
 
-# -- NumPy mirror (THE semantics) ---------------------------------------------
-def shard_seal_np(words: np.ndarray, starts: np.ndarray,
-                  n_seg: np.ndarray, n_words: np.ndarray) -> np.ndarray:
-    """Per-row ``batch_seal_np``: fold row ``k``'s ``n_seg[k]`` segments
-    over its ``n_words[k]`` live words; padded output cells = MIX_SEED."""
-    words = np.asarray(words, np.uint32)
-    starts = np.asarray(starts, np.int64)
-    K, B = starts.shape
-    out = np.full((K, B), MIX_SEED, np.uint32)
-    for k in range(K):
-        ns, nw = int(n_seg[k]), int(n_words[k])
-        if ns == 0:
-            continue
-        w = words[k, :nw]
-        mixed = (w ^ (w >> np.uint32(16))) * MIX_MULT
-        out[k, :ns] = MIX_SEED ^ np.bitwise_xor.reduceat(
-            mixed, starts[k, :ns])
-    return out
-
-
 # -- one jitted 2-D prefix-xor program ----------------------------------------
 def _lane_fold(words, starts):
     """(K, W) u32 x (K, B) i32 -> (K, B) u32 — prefix-xor per row,
     segment digests by prefix difference.  Padded starts (== n_words)
     yield MIX_SEED because their lead and last prefixes coincide."""
-    mixed = (words ^ (words >> jnp.uint32(16))) * jnp.uint32(0x85EBCA6B)
-    prefix = jax.lax.associative_scan(jnp.bitwise_xor, mixed, axis=1)
+    prefix = jax.lax.associative_scan(jnp.bitwise_xor, mix(words), axis=1)
     w = words.shape[1]
     # starts arrive as u32 (same element type as the output, so the
     # donated grid aliases it); index via i32 views — shapes are tiny
@@ -93,7 +72,7 @@ def _lane_fold(words, starts):
         prefix, jnp.maximum(ends - 1, 0), axis=1), jnp.uint32(0))
     lead = jnp.where(s32 > 0, jnp.take_along_axis(
         prefix, jnp.maximum(s32 - 1, 0), axis=1), jnp.uint32(0))
-    return jnp.uint32(0x9E3779B9) ^ (last ^ lead)
+    return seed(last ^ lead)
 
 
 # donate the starts grid: it is (K, B) i32 — the same byte layout as

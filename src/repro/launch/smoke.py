@@ -9,9 +9,10 @@ too, beside the phases, so a CPU test runs the same code at a tiny size:
     the kernel factory's own choice and once ``forced_impl("numpy")``;
     the fingerprints (state root, per-window roots, batch digests, gas
     log, blocks, event stream) must be equal bit for bit.
-  * ``run_fl`` + ``agg_errors``: the compiled Eq. 1 / Eq. 4 kernels on
-    each task's final-round inputs against their ``kernels/ref.py``
-    oracles, plus the Fig. 3 ordering (``malicious_lowest``).
+  * ``run_fl`` + ``agg_errors``: the node's Eq. 1 merge and the Eq. 4
+    distances on each task's final-round inputs against their
+    ``kernels/ref.py`` oracles, plus the Fig. 3 ordering
+    (``malicious_lowest``).
 """
 from __future__ import annotations
 
@@ -25,10 +26,10 @@ import numpy as np
 #: the paper's trainer profiles (Fig. 3), repeated to the cohort size
 BEHAVIORS = ("good", "good", "malicious", "lazy")
 
-#: Eq. 1 (MXU) and Eq. 4 (VPU) kernel tolerances against ref.py.  An f32
-#: matmul may take single-pass bf16 on the TPU in either the kernel or
-#: the XLA oracle, so Eq. 1 is held to bf16 rounding of its inputs
-#: (2^-8 of the largest merged weight); Eq. 4 sums in f32 on both sides.
+#: Eq. 1 and Eq. 4 tolerances against ref.py.  The oracle's f32 matmul
+#: may take single-pass bf16 on the TPU (the node's merge runs at full
+#: f32), so Eq. 1 is held to bf16 rounding of its inputs (2^-8 of the
+#: largest merged weight); Eq. 4 sums in f32 on both sides.
 AGG_REL_TOL = 1e-2
 DIST_REL_TOL = 1e-4
 
@@ -149,7 +150,7 @@ def run_fl(model, opt, eval_fn, val, raw_batch_fn, *, n_trainers: int,
     from repro.fl.scheduler import Scheduler
     from repro.fl.server import AutoDFL
 
-    spec = spec or NodeSpec(use_pallas_agg=True, trainer_funds=50.0)
+    spec = spec or NodeSpec(trainer_funds=50.0)
     behaviors = [BEHAVIORS[i % len(BEHAVIORS)] for i in range(n_trainers)]
     dp = DPConfig(noise_multiplier=0.05)
     node = AutoDFL(model, opt, n_trainers, eval_fn, val, spec=spec)
@@ -167,14 +168,15 @@ def run_fl(model, opt, eval_fn, val, raw_batch_fn, *, n_trainers: int,
 
 def agg_errors(sch) -> List[Dict[str, float]]:
     """Per task, on its final round's submissions and DON scores: the
-    node's merged model and the ``weighted_agg``/``model_distance``
-    kernels (through ``kernels.ops``, compiled on a TPU) against their
-    ``kernels/ref.py`` oracles.  Errors are relative to the oracle's
-    largest magnitude; ``ok`` applies the module tolerances."""
+    node's merged model (the scheduler's XLA merge) and
+    ``core.reputation.model_distances`` (the Eq. 4 pass settlement runs)
+    against their ``kernels/ref.py`` oracles.  Errors are relative to the
+    oracle's largest magnitude; ``ok`` applies the module tolerances."""
     import jax.numpy as jnp
 
     from repro.core.aggregation import tree_flat, tree_flat_stacked
-    from repro.kernels import ops, ref
+    from repro.core.reputation import model_distances
+    from repro.kernels import ref
 
     out = []
     for rt in sch.runtimes:
@@ -183,18 +185,15 @@ def agg_errors(sch) -> List[Dict[str, float]]:
         merged = tree_flat(rt.params)
         want = np.asarray(ref.weighted_agg_ref(flat, scores), np.float64)
         scale = max(float(np.abs(want).max()), 1e-12)
-        kern = np.asarray(ops.weighted_agg(flat, scores), np.float64)
         dwant = np.asarray(ref.model_distance_ref(flat, merged), np.float64)
-        dgot = np.asarray(ops.model_distance(flat, merged), np.float64)
+        dgot = np.asarray(model_distances(flat, merged), np.float64)
         e = {"task": rt.task_id, "n": int(flat.shape[0]),
              "P": int(flat.shape[1]),
-             "agg_kernel": float(np.abs(kern - want).max()) / scale,
              "agg_node": float(np.abs(np.asarray(merged, np.float64)
                                       - want).max()) / scale,
              "distance": float(np.abs(dgot - dwant).max()
                                / max(float(np.abs(dwant).max()), 1e-12))}
-        e["ok"] = (e["agg_kernel"] <= AGG_REL_TOL
-                   and e["agg_node"] <= AGG_REL_TOL
+        e["ok"] = (e["agg_node"] <= AGG_REL_TOL
                    and e["distance"] <= DIST_REL_TOL)
         out.append(e)
     return out

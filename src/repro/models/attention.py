@@ -5,8 +5,8 @@ Design notes (TPU adaptation):
     scans over (q_chunk, kv_chunk<=q_chunk) pairs — a flash-attention-shaped
     schedule expressed at the XLA level so the dry-run cost analysis stays
     causal-honest (~S^2/2, not S^2).  The Pallas `flash_attention` kernel
-    (kernels/flash_attention.py) implements the same schedule for real TPU
-    runs (cfg-gated via use_pallas).
+    (kernels/flash_attention.py) implements the same schedule; no model
+    path calls it.
   * Decode reads a (B, S_max, Hkv, dh) KV cache; for long-context cells the
     cache seq dim is sharded over the `model` axis (KV-SP) and the softmax
     normaliser is combined across shards by GSPMD-inserted collectives.

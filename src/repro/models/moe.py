@@ -10,8 +10,8 @@ TPU adaptation notes:
     all-reduce, same as the TP attention output reduction).
   * FLOPs are honest: E*C = S*top_k*cf, so compiled compute is
     ~capacity_factor x the active-param ideal (no dense-all-experts waste).
-  * The Pallas `gmm` kernel (kernels/gmm.py) provides the sorted-token
-    megablox-style path for real TPU runs (cfg-gated via use_pallas).
+  * The Pallas `gmm` kernel (kernels/gmm.py) is the sorted-token
+    megablox-style form of this matmul; no model path calls it.
 """
 from __future__ import annotations
 

@@ -41,3 +41,15 @@ class _Strategies:
 
 
 st = _Strategies()
+
+
+@pytest.fixture
+def chip_selection(monkeypatch):
+    """The kernel choice a TPU makes, on this host: the factory's backend
+    probe answers "TPU", so every ``"auto"`` op takes its TPU impl, and
+    the Pallas kernels run interpreted, as there is no chip to compile
+    for.  ``REPRO_KERNEL_IMPL`` is cleared so the table decides."""
+    from repro.kernels import factory, ops
+    monkeypatch.delenv(factory.IMPL_ENV, raising=False)
+    monkeypatch.setattr(factory, "_ON_TPU", True)
+    monkeypatch.setattr(ops, "_interpret", lambda: True)

@@ -1,6 +1,7 @@
 """Aggregation (Eq. 1) + rollup engine: equivalence and integrity tests."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -8,10 +9,12 @@ except ImportError:   # degrade: property tests skip, the rest still run
     from conftest import given, settings, st  # noqa: F401
 
 from repro.core.aggregation import (weighted_average_flat,
-                                    weighted_average_tree)
+                                    weighted_average_tree,
+                                    weighted_average_tree_mega)
 from repro.core.gas import ROLLUP_BATCH, l1_gas, l2_gas
 from repro.core.ledger import Chain, Tx
 from repro.core.rollup import BatchProof, Rollup, state_digest
+from repro.kernels import ref
 
 
 # -- Eq. 1 -----------------------------------------------------------------------
@@ -47,13 +50,47 @@ def test_weighted_average_tree_matches_flat():
     np.testing.assert_allclose(out["a"], want_a, rtol=1e-6)
 
 
-def test_pallas_agg_matches_xla_tree_path():
-    rng = np.random.default_rng(2)
-    tree = {"w": jnp.asarray(rng.normal(size=(5, 300)), jnp.float32)}
-    s = jnp.asarray(rng.uniform(0.1, 1, 5), jnp.float32)
-    a = weighted_average_tree(tree, s, use_pallas=False)
-    b = weighted_average_tree(tree, s, use_pallas=True)
-    np.testing.assert_allclose(a["w"], b["w"], rtol=1e-5, atol=1e-6)
+def _close(got, want, dt):
+    tol = dict(rtol=2e-2, atol=2e-2) if dt == jnp.bfloat16 \
+        else dict(rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **tol)
+
+
+@pytest.mark.parametrize("n,P,dt", [
+    (2, 256, jnp.float32),
+    (4, 1000, jnp.float32),
+    (16, 8192, jnp.bfloat16),
+    (64, 4096, jnp.bfloat16),
+    (3, 130, jnp.float32),
+])
+def test_xla_merge_matches_ref(n, P, dt):
+    """The Eq. 1 merge the FL cell runs against ``kernels/ref.py``: the
+    flat form, and the megastep form over two tasks, the first holding
+    zero rows with zero weights past its submitters."""
+    rng = np.random.default_rng(n * 10_000 + P)
+    k = n + 3
+    w = jnp.asarray(rng.normal(size=(2, k, P)), dt).at[0, n:].set(0)
+    s = jnp.asarray(rng.uniform(0.05, 1.0, (2, k)),
+                    jnp.float32).at[0, n:].set(0.0)
+    want = ref.weighted_agg_ref(w[0, :n], s[0, :n])
+    _close(weighted_average_flat(w[0, :n], s[0, :n]), want, dt)
+    out = weighted_average_tree_mega({"w": w}, s)["w"]
+    _close(out[0], want, dt)
+    _close(out[1], ref.weighted_agg_ref(w[1], s[1]), dt)
+
+
+def test_xla_merge_zero_score_trainer_excluded():
+    """A trainer scored 0 has no weight in the merge (flat and megastep
+    forms), as in the oracle."""
+    w = jnp.stack([jnp.ones(256), 100.0 * jnp.ones(256)])
+    s = jnp.array([1.0, 0.0])
+    np.testing.assert_allclose(weighted_average_flat(w, s), jnp.ones(256),
+                               rtol=1e-6)
+    np.testing.assert_allclose(ref.weighted_agg_ref(w, s), jnp.ones(256),
+                               rtol=1e-6)
+    out = weighted_average_tree_mega({"w": w[None]}, s[None])
+    np.testing.assert_allclose(out["w"][0], jnp.ones(256), rtol=1e-6)
 
 
 # -- rollup engine ------------------------------------------------------------------

@@ -376,8 +376,7 @@ def test_legacy_kwargs_warn_but_work(tiny_world):
         AutoDFL(model, opt, 3, eval_fn, val, engine="vector",
                 spec=NodeSpec())
     with pytest.raises(ValueError):
-        AutoDFL(model, opt, 3, eval_fn, val, use_pallas_agg=True,
-                spec=NodeSpec())
+        AutoDFL(model, opt, 3, eval_fn, val, seed=1, spec=NodeSpec())
     with pytest.raises(ValueError):              # contradicting trainer count
         AutoDFL(model, opt, 3, eval_fn, val, spec=NodeSpec(n_trainers=8))
     with pytest.raises(ValueError):

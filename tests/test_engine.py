@@ -3,13 +3,13 @@ settlement amortization invariants, Table-I regression pins, digests."""
 import numpy as np
 import pytest
 
-from repro.core.engine import (TxArrays, VectorChain, VectorRollup,
-                               xor_fold_digest)
+from repro.core.engine import TxArrays, VectorChain, VectorRollup
 from repro.core.gas import DEFAULT_GAS, FUNCTIONS, ROLLUP_BATCH, l1_gas
 from repro.core.ledger import Chain, Tx, simulate_load
 from repro.core.rollup import Rollup
 from repro.core.tasks import TaskContract
 from repro.core.workloads import make_workload, mixed_function_workload
+from repro.kernels.digest import xor_fold_digest
 
 
 def _random_workload(rng, n):

@@ -286,15 +286,13 @@ def test_block_pack_scan_hlo_cost():
 
 
 # -- chip_smoke.py's comparisons, at a tiny size -------------------------------
-def test_smoke_ledger_auto_equals_forced_mirror(monkeypatch):
+def test_smoke_ledger_auto_equals_forced_mirror(chip_selection):
     """The smoke's ledger check: the TPU kernel selection (Pallas in
     interpret mode here) and the forced NumPy mirrors leave identical
     fingerprints; a different workload changes them."""
-    from repro.core import state
     from repro.launch.smoke import (fingerprint_diff, forced_impl,
                                     ledger_fingerprint, run_ledger)
     from repro.kernels.factory import resolve_impl
-    monkeypatch.setattr(state, "_ON_TPU", True)
     assert resolve_impl("batch_seal") == "pallas"
     auto = ledger_fingerprint(run_ledger(300.0, 3.0, 5000))
     with forced_impl("numpy"):
@@ -307,15 +305,17 @@ def test_smoke_ledger_auto_equals_forced_mirror(monkeypatch):
 
 
 def test_smoke_fl_checks(tiny_world):
-    """The smoke's FL checks on a tiny cohort: the Pallas Eq. 1/Eq. 4
-    kernels agree with ref.py, a corrupted merge is caught, and the
-    malicious trainer ends lowest."""
+    """The smoke's FL checks on a tiny cohort: the node's megastep merge
+    and the Eq. 4 distances agree with ref.py, a corrupted merge is
+    caught, and the malicious trainer ends lowest."""
     import jax
+    from repro.core.aggregation import weighted_average_tree_mega
     from repro.launch.smoke import agg_errors, malicious_lowest, run_fl
     model, opt, val, bf, eval_fn, _ = tiny_world
     node, sch, res = run_fl(model, opt, eval_fn, val, bf, n_trainers=4,
                             n_tasks=1, rounds=2)
-    assert set(res) == {"task0"} and node.use_pallas_agg
+    assert set(res) == {"task0"} and sch.mega_windows > 0
+    assert weighted_average_tree_mega._cache_size() > 0
     errs = agg_errors(sch)
     assert [e["ok"] for e in errs] == [True]
     assert malicious_lowest(node)

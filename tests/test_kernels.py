@@ -1,4 +1,9 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracles."""
+"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracles,
+the ledger ops' device impls pinned bit-exact to their NumPy mirrors, and
+the kernel factory's selection."""
+import ast
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,9 +12,7 @@ import pytest
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gmm import gmm
-from repro.kernels.model_distance import model_distance
 from repro.kernels.rollup_digest import rollup_digest
-from repro.kernels.weighted_agg import weighted_agg
 
 RNG = np.random.default_rng(42)
 
@@ -17,43 +20,6 @@ RNG = np.random.default_rng(42)
 def _tol(dt):
     return dict(rtol=2e-2, atol=2e-2) if dt == jnp.bfloat16 \
         else dict(rtol=1e-4, atol=1e-5)
-
-
-@pytest.mark.parametrize("n,P,dt,block", [
-    (2, 256, jnp.float32, 128),
-    (4, 1000, jnp.float32, 512),       # padded tail
-    (16, 8192, jnp.bfloat16, 2048),
-    (64, 4096, jnp.bfloat16, 4096),
-    (3, 130, jnp.float32, 512),        # P < block
-])
-def test_weighted_agg_sweep(n, P, dt, block):
-    w = jnp.asarray(RNG.normal(size=(n, P)), dt)
-    s = jnp.asarray(RNG.uniform(0.05, 1.0, n), jnp.float32)
-    got = weighted_agg(w, s, block_p=block, interpret=True)
-    want = ops.weighted_agg_ref(w, s)
-    np.testing.assert_allclose(got.astype(np.float32),
-                               want.astype(np.float32), **_tol(dt))
-
-
-def test_weighted_agg_zero_score_trainer_excluded():
-    w = jnp.stack([jnp.ones(256), 100.0 * jnp.ones(256)])
-    s = jnp.array([1.0, 0.0])
-    out = weighted_agg(w.astype(jnp.float32), s, block_p=128, interpret=True)
-    np.testing.assert_allclose(out, jnp.ones(256), rtol=1e-6)
-
-
-@pytest.mark.parametrize("n,P,dt", [
-    (4, 1000, jnp.float32),
-    (8, 5000, jnp.bfloat16),
-    (1, 128, jnp.float32),
-])
-def test_model_distance_sweep(n, P, dt):
-    l = jnp.asarray(RNG.normal(size=(n, P)), dt)
-    g = jnp.asarray(RNG.normal(size=(P,)), dt)
-    got = model_distance(l, g, block_p=512, interpret=True)
-    want = ops.model_distance_ref(l, g)
-    np.testing.assert_allclose(got, want, rtol=3e-2 if dt == jnp.bfloat16
-                               else 1e-4)
 
 
 @pytest.mark.parametrize("B,S,H,Hkv,dh,dt", [
@@ -112,7 +78,7 @@ def test_rollup_digest_detects_tampering():
     assert d0 != d1
 
 
-# -- ledger hot-path kernels: numpy / jax / pallas pinned BIT-EXACT -----------
+# -- ledger hot-path kernels: device impls pinned BIT-EXACT to the mirrors ----
 # (these are integer/bit-pattern kernels — no tolerance, any backend, any
 # JAX_ENABLE_X64 setting; CI runs this module on the {x64 on, x64 off}
 # matrix with JAX_PLATFORMS=cpu pinned)
@@ -181,9 +147,12 @@ def test_block_pack_matches_stepped_produce_block():
     (128, 128, 3),                     # one word per segment
     (40_000, 3, 5),                    # rows longer than one kernel block
 ])
-def test_batch_seal_impls_bit_exact(n_words, n_segs, seed):
-    from repro.kernels.batch_seal import (batch_seal_jax, batch_seal_np,
-                                          batch_seal_pallas)
+def test_batch_seal_impls_bit_exact(chip_selection, n_words, n_segs, seed):
+    """The chip's batch_seal (Pallas, interpreted here) through the
+    factory equals the mirror."""
+    from repro.kernels.digest import batch_seal_np
+    from repro.kernels.factory import get_kernel, resolve_impl
+    assert resolve_impl("batch_seal") == "pallas"
     g = np.random.default_rng(seed)
     words = g.integers(0, 2**32, n_words, dtype=np.uint64).astype(np.uint32)
     cuts = np.sort(g.choice(np.arange(1, n_words), n_segs - 1,
@@ -192,15 +161,13 @@ def test_batch_seal_impls_bit_exact(n_words, n_segs, seed):
     starts = np.concatenate([[0], cuts]).astype(np.int64)
     want = batch_seal_np(words, starts)
     assert want.dtype == np.uint32 and want.shape == (n_segs,)
-    np.testing.assert_array_equal(batch_seal_jax(words, starts), want)
-    np.testing.assert_array_equal(
-        batch_seal_pallas(words, starts, interpret=True), want)
+    np.testing.assert_array_equal(get_kernel("batch_seal")(words, starts),
+                                  want)
 
 
 def test_batch_seal_matches_single_digest():
     """One segment == the scalar xor_fold_digest the object path uses."""
-    from repro.core.engine import xor_fold_digest
-    from repro.kernels.batch_seal import batch_seal_np
+    from repro.kernels.digest import batch_seal_np, xor_fold_digest
     g = np.random.default_rng(5)
     words = g.integers(0, 2**32, 777, dtype=np.uint64).astype(np.uint32)
     out = batch_seal_np(words, np.array([0], np.int64))
@@ -215,9 +182,9 @@ def test_batch_seal_matches_single_digest():
     (300_000, 146, 4),                 # every chunk dirty (dup ids too)
 ])
 def test_dirty_fold_impls_bit_exact(n_words, n_dirty, seed):
-    from repro.core.state import STATE_CHUNK_WORDS, chunk_fold_digests
-    from repro.kernels.dirty_fold import (dirty_fold_jax, dirty_fold_np,
-                                          dirty_fold_pallas)
+    from repro.core.state import STATE_CHUNK_WORDS
+    from repro.kernels.digest import chunk_fold_digests, dirty_fold_np
+    from repro.kernels.dirty_fold import dirty_fold_pallas
     g = np.random.default_rng(seed)
     words = g.integers(0, 2**32, n_words, dtype=np.uint64).astype(np.uint32)
     n_chunks = -(-n_words // STATE_CHUNK_WORDS)
@@ -228,41 +195,36 @@ def test_dirty_fold_impls_bit_exact(n_words, n_dirty, seed):
     assert got.dtype == np.uint32
     np.testing.assert_array_equal(got, want)
     np.testing.assert_array_equal(
-        dirty_fold_jax(words, ids, STATE_CHUNK_WORDS), want)
-    np.testing.assert_array_equal(
         dirty_fold_pallas(words, ids, STATE_CHUNK_WORDS, interpret=True),
         want)
 
 
 def test_dirty_fold_empty_ids():
     from repro.core.state import STATE_CHUNK_WORDS
-    from repro.kernels.dirty_fold import (dirty_fold_jax, dirty_fold_np,
-                                          dirty_fold_pallas)
+    from repro.kernels.digest import dirty_fold_np
+    from repro.kernels.dirty_fold import dirty_fold_pallas
     words = np.arange(4096, dtype=np.uint32)
     none = np.empty(0, np.int64)
-    for impl in (dirty_fold_np, dirty_fold_jax, dirty_fold_pallas):
+    for impl in (dirty_fold_np, dirty_fold_pallas):
         out = impl(words, none, STATE_CHUNK_WORDS)
         assert out.shape == (0,) and out.dtype == np.uint32
 
 
-def _resident_impl(name):
-    from repro.kernels.dirty_fold import dirty_fold_jax, dirty_fold_pallas
-    if name == "pallas":
-        return lambda *a, **k: dirty_fold_pallas(*a, interpret=True, **k)
-    return dirty_fold_jax
+def _resident_fold(*a, **k):
+    from repro.kernels.dirty_fold import dirty_fold_pallas
+    return dirty_fold_pallas(*a, interpret=True, **k)
 
 
-@pytest.mark.parametrize("impl", ["jax", "pallas"])
 @pytest.mark.parametrize("n_words,seed", [(5_000, 11), (70_000, 12)])
-def test_dirty_fold_resident_windows_match_mirror(impl, n_words, seed):
+def test_dirty_fold_resident_windows_match_mirror(n_words, seed):
     """A holder over a sequence of windows of random word patches: each
     window stages only its touched words, the digests equal the mirror's
     at every step, the device copy equals the host buffer at the end, and
     the whole buffer was uploaded once."""
     from repro import obs
     from repro.core.state import STATE_CHUNK_WORDS as C
-    from repro.kernels.dirty_fold import UPLOADS, Resident, dirty_fold_np
-    fold = _resident_impl(impl)
+    from repro.kernels.digest import dirty_fold_np
+    from repro.kernels.dirty_fold import UPLOADS, Resident
     g = np.random.default_rng(seed)
     words = g.integers(0, 2**32, n_words, dtype=np.uint64).astype(np.uint32)
     res = Resident()
@@ -272,7 +234,7 @@ def test_dirty_fold_resident_windows_match_mirror(impl, n_words, seed):
         words[touched] = g.integers(0, 2**32, touched.size,
                                     dtype=np.uint64).astype(np.uint32)
         ids = np.unique(touched // C)
-        got = fold(words, ids, C, resident=res, touched=touched)
+        got = _resident_fold(words, ids, C, resident=res, touched=touched)
         np.testing.assert_array_equal(got, dirty_fold_np(words, ids, C))
     host = np.asarray(res.lanes).ravel()
     np.testing.assert_array_equal(host[:n_words], words)
@@ -281,25 +243,24 @@ def test_dirty_fold_resident_windows_match_mirror(impl, n_words, seed):
     obs.reset()
 
 
-@pytest.mark.parametrize("impl", ["jax", "pallas"])
-def test_dirty_fold_resident_window_with_no_touched_words(impl):
+def test_dirty_fold_resident_window_with_no_touched_words():
     """Nothing touched and nothing dirty: no device work, no upload.
     Dirty ids with nothing touched fold the resident copy as it stands."""
     from repro import obs
     from repro.core.state import STATE_CHUNK_WORDS as C
-    from repro.kernels.dirty_fold import UPLOADS, Resident, dirty_fold_np
-    fold = _resident_impl(impl)
+    from repro.kernels.digest import dirty_fold_np
+    from repro.kernels.dirty_fold import UPLOADS, Resident
     words = np.arange(3 * C, dtype=np.uint32)
     none = np.empty(0, np.int64)
     res = Resident()
     obs.reset()
-    out = fold(words, none, C, resident=res, touched=none)
+    out = _resident_fold(words, none, C, resident=res, touched=none)
     assert out.shape == (0,) and out.dtype == np.uint32
     assert res.lanes is None and UPLOADS not in obs.counters()
     ids = np.array([0, 2])
     for _ in range(2):
         np.testing.assert_array_equal(
-            fold(words, ids, C, resident=res, touched=none),
+            _resident_fold(words, ids, C, resident=res, touched=none),
             dirty_fold_np(words, ids, C))
     assert obs.counters()[UPLOADS] == 1
     obs.reset()
@@ -307,16 +268,13 @@ def test_dirty_fold_resident_window_with_no_touched_words(impl):
 
 @pytest.mark.parametrize("n", [0, 1, 7, 513, 4096])
 def test_rollup_digest_factory_impls_bit_exact(n):
-    """The factory's three rollup_digest impls agree bit-for-bit with the
-    NumPy semantics-of-record mirror (R002's machine-checked contract).
-    The pallas impl runs un-interpreted only on TPU, so parity for it is
-    pinned at the kernel level (test_rollup_digest_sweep); here the
-    portable numpy/jax pair must match on any backend."""
+    """The factory's rollup_digest impls agree bit-for-bit: the chip's
+    Pallas kernel (interpreted here) against the NumPy mirror."""
     from repro.kernels import factory
     rng = np.random.default_rng(2024 + n)
     words = rng.integers(0, 2**32, n, dtype=np.uint32)
     want = factory.get_kernel("rollup_digest", "numpy")(words)
-    got = factory.get_kernel("rollup_digest", "jax")(words)
+    got = factory.get_kernel("rollup_digest", "pallas")(words)
     assert got == want
 
 
@@ -325,18 +283,15 @@ def test_kernel_factory_selection():
     from repro.kernels.block_pack import block_pack_np
     assert factory.get_kernel("block_pack", "numpy") is block_pack_np
     assert set(factory.available_impls("block_pack")) == {"numpy", "jax"}
-    assert set(factory.available_impls("batch_seal")) == \
-        {"numpy", "jax", "pallas"}
-    assert set(factory.available_impls("dirty_fold")) == \
-        {"numpy", "jax", "pallas"}
-    assert set(factory.available_impls("rollup_digest")) == \
-        {"numpy", "jax", "pallas"}
+    for op in ("batch_seal", "dirty_fold", "rollup_digest"):
+        assert set(factory.available_impls(op)) == {"numpy", "pallas"}, op
+    assert set(factory.available_impls("shard_seal")) == \
+        {"numpy", "jax", "shard_map"}
     with pytest.raises(KeyError, match="unknown kernel op"):
         factory.get_kernel("no_such_op")
     with pytest.raises(KeyError, match="no impl"):
         factory.get_kernel("block_pack", "cuda")
     # env-var override is honored by the default resolution path
-    import os
     old = os.environ.get("REPRO_KERNEL_IMPL")
     os.environ["REPRO_KERNEL_IMPL"] = "numpy"
     try:
@@ -349,3 +304,52 @@ def test_kernel_factory_selection():
             del os.environ["REPRO_KERNEL_IMPL"]
         else:
             os.environ["REPRO_KERNEL_IMPL"] = old
+
+
+#: the factory's whole "auto" table: op -> (cpu choice, tpu choice)
+AUTO_TABLE = {
+    "block_pack": ("jax", "jax"),
+    "batch_seal": ("numpy", "pallas"),
+    "rollup_digest": ("numpy", "pallas"),
+    "dirty_fold": ("numpy", "pallas"),
+    "shard_seal": ("numpy", "jax"),
+}
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("op", sorted(AUTO_TABLE))
+def test_auto_selection_by_the_probe(monkeypatch, op, backend):
+    """With no override, ``on_tpu()`` alone picks each op's impl."""
+    from repro.kernels import factory
+    monkeypatch.delenv(factory.IMPL_ENV, raising=False)
+    monkeypatch.setattr(factory, "_ON_TPU", backend == "tpu")
+    assert factory.on_tpu() is (backend == "tpu")
+    want = AUTO_TABLE[op][backend == "tpu"]
+    assert factory.resolve_impl(op) == want
+    assert factory.available_ops() == tuple(sorted(AUTO_TABLE))
+
+
+def test_kernels_import_nothing_from_the_layers_above():
+    """``repro.kernels`` owns the digest format and the kernel choice:
+    no module of it imports ``repro.core``, ``api``, ``fl`` or ``serve``
+    (at the top or inside a function)."""
+    import repro.kernels
+    above = ("repro.core", "repro.api", "repro.fl", "repro.serve")
+    root = os.path.dirname(repro.kernels.__file__)
+    found = []
+    for name in sorted(os.listdir(root)):
+        if not name.endswith(".py"):
+            continue
+        with open(os.path.join(root, name), encoding="utf-8") as fh:
+            tree = ast.parse(fh.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                mods = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and node.module:
+                mods = [node.module] + [f"{node.module}.{a.name}"
+                                        for a in node.names]
+            else:
+                continue
+            found += [f"{name}:{node.lineno} {m}" for m in mods
+                      if any(m == a or m.startswith(a + ".") for a in above)]
+    assert found == []

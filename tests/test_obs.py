@@ -1,10 +1,11 @@
 """Program spans and counters (``repro.obs``) on one fused window.
 
-One CPU-size ``FusedWindowLoop`` window over the default node: under
+One CPU-size ``FusedWindowLoop`` window over the default node, with the
+chip's kernel selection (Pallas interpreted here): under
 ``jax.profiler.trace`` every layer span shows and nests as the layers
-do (kernel call in commitment in seal); with the device impls selected
-the window counts itself, its kernel calls and the bytes each call
-copies to the device (the committed words once, then only what a window
+do (kernel call in commitment in seal); the window counts itself, its
+kernel calls and the bytes each call copies to the device (the
+committed words once, by the first root, then only what a window
 touched); the NumPy mirrors stay unwrapped and uncounted;
 the node service reports the counters under ``"node"``; a blob put
 counts its path (raw array tree or pickle) and the bytes it hashed.
@@ -91,8 +92,7 @@ def _inside(spans, child, parent):
                                   for p in outer) for c in kids)
 
 
-def test_window_spans_nest_as_the_layers(monkeypatch, tmp_path):
-    monkeypatch.setenv("REPRO_KERNEL_IMPL", "jax")
+def test_window_spans_nest_as_the_layers(chip_selection, tmp_path):
     client, _ = _node()
     with jax.profiler.trace(str(tmp_path)):
         with jax.profiler.TraceAnnotation("ledger.execute"):
@@ -110,13 +110,14 @@ def test_window_spans_nest_as_the_layers(monkeypatch, tmp_path):
     assert not _inside(spans, "ledger.events", "ledger.pack")
 
 
-def test_one_window_counts_itself_its_kernels_and_their_bytes(monkeypatch):
+def test_one_window_counts_itself_its_kernels_and_their_bytes(
+        chip_selection):
     """Over two windows: the refold keeps the committed words on the
-    device, so after the first window (which uploads them once) a window
+    device, so after the first root (which uploads them once) a window
     stages only the words it touched, well under 1% of the buffer at
-    2^18 accounts."""
-    monkeypatch.setenv("REPRO_KERNEL_IMPL", "jax")
+    2^18 accounts, and uploads nothing."""
     client, state = _node(1 << 18)
+    assert obs.counters()["kernel.uploads.dirty_fold"] == 1
     obs.reset()
     _window(client)
     c = obs.counters()
@@ -132,7 +133,7 @@ def test_one_window_counts_itself_its_kernels_and_their_bytes(monkeypatch):
     staged = (c2["kernel.h2d_bytes.dirty_fold"]
               - c["kernel.h2d_bytes.dirty_fold"])
     assert 0 < staged < 0.01 * words.nbytes
-    assert c2["kernel.uploads.dirty_fold"] <= 1
+    assert "kernel.uploads.dirty_fold" not in c2
 
 
 def test_numpy_mirrors_are_not_counted(monkeypatch):

@@ -26,7 +26,7 @@ import pytest
 from repro.api import (ChainSpec, NodeClient, NodeSpec, ProverSpec,
                        RollupSpec, ShardSpec)
 from repro.core.gas import DEFAULT_GAS
-from repro.core.state import chunk_fold_digests
+from repro.kernels.digest import chunk_fold_digests
 
 BACKENDS = [
     NodeSpec(),                                         # VectorRollup

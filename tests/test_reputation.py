@@ -19,12 +19,20 @@ P = ReputationParams()
 
 
 # -- Eq. 4 / Eq. 3 --------------------------------------------------------------
-def test_model_distance_matches_numpy():
-    rng = np.random.default_rng(0)
-    local = rng.normal(size=(5, 257)).astype(np.float32)
-    glob = rng.normal(size=(257,)).astype(np.float32)
-    got = model_distances(jnp.asarray(local), jnp.asarray(glob))
-    want = np.linalg.norm(local - glob[None], axis=1)
+@pytest.mark.parametrize("n,P,dt", [
+    (5, 257, jnp.float32),
+    (4, 1000, jnp.float32),
+    (8, 5000, jnp.bfloat16),
+    (1, 128, jnp.float32),
+])
+def test_model_distance_matches_numpy(n, P, dt):
+    """Eq. 4 as settlement runs it (f32 sums, bf16 inputs widened)."""
+    rng = np.random.default_rng(n * 10_000 + P)
+    local = jnp.asarray(rng.normal(size=(n, P)), dt)
+    glob = jnp.asarray(rng.normal(size=(P,)), dt)
+    got = model_distances(local, glob)
+    want = np.linalg.norm(np.asarray(local, np.float64)
+                          - np.asarray(glob, np.float64)[None], axis=1)
     np.testing.assert_allclose(got, want, rtol=1e-5)
 
 

@@ -19,10 +19,9 @@ import numpy as np
 import pytest
 
 from repro.core.engine import xor_fold_digest_segments
-from repro.core.state import MIX_SEED
+from repro.kernels.digest import MIX_SEED, shard_seal_np
 from repro.kernels.factory import get_kernel
-from repro.kernels.shard_lanes import (shard_seal_jax, shard_seal_np,
-                                       shard_seal_shard_map)
+from repro.kernels.shard_lanes import shard_seal_jax, shard_seal_shard_map
 
 IMPLS = {"numpy": shard_seal_np, "jax": shard_seal_jax,
          "shard_map": shard_seal_shard_map}

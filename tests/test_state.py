@@ -10,8 +10,8 @@ from repro.core.engine import TxArrays, VectorChain, VectorRollup
 from repro.core.ledger import Chain, LedgerBackend, Tx
 from repro.core.rollup import Rollup, state_digest
 from repro.core.state import (STATE_SCHEMA, StateArrays, canonical_bytes,
-                              chunk_fold_digests, chunked_root,
-                              default_state_handlers)
+                              chunked_root, default_state_handlers)
+from repro.kernels.digest import chunk_fold_digests
 
 
 # -- satellite: canonical byte encoding fixes the repr-truncation collision ----
@@ -78,14 +78,14 @@ def test_chunk_fold_digests_match_pallas_kernel(n):
 def test_chunked_root_deterministic_and_tamper_evident():
     rng = np.random.default_rng(3)
     words = rng.integers(0, 2**32, 10_000, dtype=np.uint32)
-    r1 = chunked_root(words, backend="numpy")
-    r2 = chunked_root(words.copy(), backend="numpy")
+    r1 = chunked_root(words)
+    r2 = chunked_root(words.copy())
     assert r1 == r2
     tampered = words.copy()
     tampered[9_999] ^= 1
-    assert chunked_root(tampered, backend="numpy") != r1
+    assert chunked_root(tampered) != r1
     # the header participates: same words, different schema -> new root
-    assert chunked_root(words, backend="numpy", header=b"v2") != r1
+    assert chunked_root(words, header=b"v2") != r1
 
 
 # -- StateArrays ----------------------------------------------------------------
@@ -176,14 +176,13 @@ def _touch(s, rng, n_rows):
     s.mark_dirty(ids)
 
 
-@pytest.fixture(params=["jax", "pallas"])
-def device_impl(request, monkeypatch):
-    """Force the device impls of ``dirty_fold`` (Pallas interpreted off
-    the chip) and count afresh."""
+@pytest.fixture
+def device_impl(chip_selection):
+    """The chip's ``dirty_fold`` (Pallas, interpreted here), counted
+    afresh."""
     from repro import obs
-    monkeypatch.setenv("REPRO_KERNEL_IMPL", request.param)
     obs.reset()
-    yield request.param
+    yield
     obs.reset()
 
 
@@ -250,7 +249,7 @@ def test_resident_copy_dropped_when_the_mirror_refolds(device_impl,
     monkeypatch.setenv("REPRO_KERNEL_IMPL", "numpy")
     _touch(s, rng, 30)
     assert s.root() == s.copy().root()
-    monkeypatch.setenv("REPRO_KERNEL_IMPL", device_impl)
+    monkeypatch.delenv("REPRO_KERNEL_IMPL")
     _touch(s, rng, 30)
     assert s.root() == s.copy().root()
     assert _uploads() == 2

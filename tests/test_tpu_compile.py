@@ -11,9 +11,7 @@ shapes ``chip_smoke.py`` produces:
   * the resident ``dirty_fold`` patch-and-fold program at the benchmark
     cell's window (2^20 accounts: 5,632 chunks of 2,048 u32 words held
     as 128-lane rows, 16,384 touched words, 4,096 dirty chunk ids, each
-    count's pow2 bucket);
-  * ``weighted_agg`` and ``model_distance`` at LeNet-5's parameter count
-    (61,706) over its 8-trainer cohort.
+    count's pow2 bucket).
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and a module that decided at import
@@ -27,7 +25,6 @@ import jax.numpy as jnp
 import pytest
 
 STATE_WORDS = 544 * 2048          # 100k accounts x 11 words, chunk-padded
-LENET5_PARAMS = 61_706
 
 
 @pytest.fixture(scope="module")
@@ -55,12 +52,9 @@ def _case(name):
     """(kernel callable, [(shape, dtype), ...]) for one compile case."""
     from repro.kernels.batch_seal import SEAL_BLOCK_W, _seal_pallas_call
     from repro.kernels.dirty_fold import _patch_fold
-    from repro.kernels.rollup_digest import row_fold_call
-    from repro.kernels.model_distance import model_distance
     from repro.kernels.rollup_digest import (rollup_chunk_digests,
-                                             rollup_digest)
-    from repro.kernels.weighted_agg import weighted_agg
-    u32, i32, f32 = jnp.uint32, jnp.int32, jnp.float32
+                                             rollup_digest, row_fold_call)
+    u32, i32 = jnp.uint32, jnp.int32
     return {
         # merged-buffer digest over the whole state word buffer
         "rollup_digest": (rollup_digest, [((STATE_WORDS,), u32)]),
@@ -87,20 +81,15 @@ def _case(name):
         # dirty chunks (bucket 4,096)
         "dirty_fold_resident": (
             lambda b, i, v, d: _patch_fold(b, i, v, d, chunk=2048,
-                                           fold="pallas", interpret=False),
+                                           interpret=False),
             [((5632 * 16, 128), u32), ((16384,), i32), ((16384,), u32),
              ((4096,), i32)]),
-        "weighted_agg": (weighted_agg, [((8, LENET5_PARAMS), f32),
-                                        ((8,), f32)]),
-        "model_distance": (model_distance, [((8, LENET5_PARAMS), f32),
-                                            ((LENET5_PARAMS,), f32)]),
     }[name]
 
 
 @pytest.mark.parametrize("name", [
     "rollup_digest", "rollup_chunk_digests", "batch_seal",
     "batch_seal_long_rows", "dirty_fold", "dirty_fold_resident",
-    "weighted_agg", "model_distance",
 ])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = _case(name)
