@@ -563,7 +563,7 @@ class FusedWindowLoop:
             return
         with obs.span("ledger.pool"):
             self.chain._consolidate()
-        obs.count("pack.rows", self.chain._n)
+        obs.count("pack.rows", self.chain._n - self.chain._ptr)
         with obs.span("ledger.pack"):
             ntx, gas_used, height0 = self._pack(times, n_vis)
         with obs.span("ledger.events"):
@@ -574,16 +574,24 @@ class FusedWindowLoop:
               ) -> Tuple[np.ndarray, np.ndarray, int]:
         """Pack the consolidated mempool into the deferred blocks, stamp
         confirm times, append the ``BlockStats`` and dispatch handlers.
-        Returns per-block tx counts, gas used and the first new height."""
+        Returns per-block tx counts, gas used and the first new height.
+
+        The packer gets only the unconfirmed tail ``[_ptr, _n)``, its gas
+        cumsum rebased to start at 0: ``_tmax`` is the running max over
+        the whole history, so searching the tail equals searching it all
+        clamped at ``_ptr``.  Its stops come back shifted by ``_ptr``;
+        everything after the call is on absolute indices."""
         from repro.kernels.factory import get_kernel
         chain = self.chain
         nblk = times.shape[0]
-        ptr0 = chain._ptr
-        stops = np.asarray(get_kernel("block_pack")(
-            chain._tmax[: chain._n], chain._gcum[: chain._n], times,
-            n_vis, chain.block_gas_limit, ptr0), np.int64)
+        ptr0, n = chain._ptr, chain._n
+        base = chain._gcum[ptr0 - 1] if ptr0 else 0
+        stops = ptr0 + np.asarray(get_kernel("block_pack")(
+            chain._tmax[ptr0:n], chain._gcum[ptr0:n] - base, times,
+            np.maximum(n_vis - ptr0, 0), chain.block_gas_limit, 0),
+            np.int64)
         starts = np.concatenate([[ptr0], stops[:-1]])
-        if chain._n:
+        if n:
             gend = np.where(stops > 0,
                             chain._gcum[np.maximum(stops - 1, 0)], 0)
             gprev = np.where(starts > 0,

@@ -270,6 +270,103 @@ def test_fused_adopts_preexisting_pending():
     _assert_ledgers_equal(_N(ca, ra), _N(cb, rb))
 
 
+# -- the packer sees only the unconfirmed tail ---------------------------------
+def _tail_windows(fns):
+    """Chain-only traffic, one ``TxArrays`` (or None) a 1 s window: light
+    windows that drain, bursts over the block gas cap, a stuck head-of-
+    line tx stamped 2.5 s ahead, and windows with nothing to pack."""
+    rng = np.random.default_rng(7)
+    fid = fns.id("bgPing")
+    out = []
+    for w in range(80):
+        if w in (5, 6, 30):                      # nothing submitted
+            out.append(None)
+            continue
+        k = 16 if w % 9 == 3 else int(rng.integers(3, 9))
+        t = np.sort(rng.uniform(w, w + 1.0, k))
+        if w == 12:                              # stuck head of line
+            t[0] = w + 3.5
+        out.append(TxArrays(t, rng.integers(21_000, 60_000, k)
+                            .astype(np.int64),
+                            np.full(k, fid, np.int32),
+                            rng.integers(0, 64, k).astype(np.int32), fns))
+    return out
+
+
+def test_fused_packs_only_the_unconfirmed_tail(monkeypatch):
+    """A fused loop a window over one chain whose history grows past 20x
+    a window's rows: every ``block_pack`` call gets the unconfirmed tail
+    ``[_ptr, _n)`` with its gas rebased and ``ptr0 == 0``, ``pack.rows``
+    counts that tail, and blocks, confirm times, receipts and
+    ``BlockPacked`` events equal the stepped ``produce_block`` run."""
+    from repro import obs
+    from repro.kernels import factory
+    fns = FnRegistry()
+    windows = _tail_windows(fns)
+    limit = 200_000                    # about 5 txs: bursts carry over
+
+    stepped = VectorChain(block_time=0.25, block_gas_limit=limit)
+    for w, b in enumerate(windows):
+        if b is not None:
+            stepped.submit_arrays(b)
+        stepped.run_until(w + 1.0)
+
+    chain = VectorChain(block_time=0.25, block_gas_limit=limit)
+    real = factory.get_kernel("block_pack")
+    tails: list = []
+
+    def spy(tmax, gcum, times, n_vis, gas_limit, ptr0):
+        lo, hi = chain._ptr, chain._n
+        assert ptr0 == 0 and len(tmax) == len(gcum) == hi - lo
+        np.testing.assert_array_equal(tmax, chain._tmax[lo:hi])
+        np.testing.assert_array_equal(
+            gcum, chain._gcum[lo:hi] - (chain._gcum[lo - 1] if lo else 0))
+        assert ((0 <= n_vis) & (n_vis <= hi - lo)).all()
+        tails.append((lo, hi))
+        return real(tmax, gcum, times, n_vis, gas_limit, ptr0)
+
+    monkeypatch.setitem(factory._REGISTRY["block_pack"],
+                        factory.resolve_impl("block_pack"), spy)
+    rows, carry, blocks_per = [], [], []
+    for w, b in enumerate(windows):
+        obs.reset()
+        h0 = len(chain.blocks)
+        loop = FusedWindowLoop(chain)
+        if b is not None:
+            loop.submit(chain, b)
+        loop.run_until(w + 1.0)
+        loop.execute()
+        lo, hi = tails[-1]
+        assert obs.counters()["pack.rows"] == hi - lo
+        rows.append(hi - lo)
+        carry.append(chain._n - chain._ptr)
+        blocks_per.append(len(chain.blocks) - h0)
+    obs.reset()
+
+    assert len(tails) == len(windows)
+    biggest = max(len(b) for b in windows if b is not None)
+    assert chain._n >= 20 * biggest
+    assert tails[0][0] == 0 and tails[0][1] > 0            # ptr0 == 0
+    assert 0 in rows                                        # empty tail
+    assert min(blocks_per) == max(blocks_per) == 4          # multi-block
+    # the tail stays within a window's rows plus the carry over a burst
+    # or the stuck head, however long the history grows
+    assert max(rows) <= 4 * biggest
+    assert max(rows[-10:]) <= 4 * biggest < chain._n // 5
+    # the gas cap binds: a block leaves a time-eligible tx behind
+    n = stepped._n
+    assert any(blk.stop < n and stepped._tmax[blk.stop] <= blk.time
+               for blk in stepped.blocks[1:])
+    # the stuck head holds its window's rows and the next ones
+    assert carry[11] == 0 and carry[12] > 0 and carry[13] > len(windows[13])
+
+    _assert_ledgers_equal(_N(stepped, None), _N(chain, None))
+    assert chain._ptr == stepped._ptr == chain._n
+    for i in range(chain._n):
+        assert chain.block_of(i) == stepped.block_of(i)
+        assert chain.confirm_time_of(i) == stepped.confirm_time_of(i)
+
+
 # -- fused program shape: HLO cost of the packing scan ------------------------
 def test_block_pack_scan_hlo_cost():
     from repro.analysis.hlo_cost import analyze

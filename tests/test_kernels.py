@@ -95,12 +95,24 @@ def _pack_stream(n_txs, n_blocks, seed, gas_limit):
     return tmax, gcum, times, n_vis, gas_limit
 
 
+def _pack_tail(pack, tmax, gcum, times, n_vis, gas_limit, p):
+    """Pack from the tail ``[p, n)`` alone, its gas cumsum rebased and
+    the stops shifted back by ``p`` (what the fused loop hands the
+    packer: only the unconfirmed rows)."""
+    base = gcum[p - 1] if p else 0
+    return p + pack(tmax[p:], gcum[p:] - base, times,
+                    np.maximum(n_vis - p, 0), gas_limit, 0)
+
+
 @pytest.mark.parametrize("n_txs,n_blocks,seed,gas_limit", [
     (1, 1, 0, 9_000_000),
     (100, 7, 1, 9_000_000),
     (1000, 33, 2, 300_000),            # gas-capped: head-of-line carry
     (513, 16, 3, 2**40),               # limit above any cumsum: time-bound
     (64, 5, 4, 21_000),                # ~one tx per block
+    (0, 3, 5, 9_000_000),              # empty mempool
+    (2000, 40, 6, 500_000),            # long history, gas-capped
+    (300, 2, 7, 9_000_000),            # few blocks over a long mempool
 ])
 def test_block_pack_impls_bit_exact(n_txs, n_blocks, seed, gas_limit):
     from repro.kernels.block_pack import block_pack_jax, block_pack_np
@@ -112,6 +124,15 @@ def test_block_pack_impls_bit_exact(n_txs, n_blocks, seed, gas_limit):
     p0 = int(want[0])
     want_p = block_pack_np(*args, p0)
     np.testing.assert_array_equal(block_pack_jax(*args, p0), want_p)
+    # packing the tail [p, n) alone gives the same stops as packing the
+    # whole history from p: at the start, after the first and the last
+    # block, at the end (empty tail) and at a random point
+    g = np.random.default_rng(seed + 100)
+    for p in {0, p0, int(want[-1]), n_txs, int(g.integers(0, n_txs + 1))}:
+        want_p = block_pack_np(*args, p)
+        for pack in (block_pack_np, block_pack_jax):
+            np.testing.assert_array_equal(_pack_tail(pack, *args, p),
+                                          want_p, err_msg=f"p={p}")
 
 
 def test_block_pack_matches_stepped_produce_block():

@@ -55,19 +55,20 @@ def _node(n_accounts=N_ACCOUNTS):
     return client, state
 
 
-def _window(client, n=300, seed=0):
+def _window(client, n=300, seed=0, w=0):
+    """Window ``w``: ``n`` txs over ``[w, w + 1)``, run to ``w + 1``."""
     rng = np.random.default_rng(seed)
     fns = FnRegistry(["publishTask", "submitLocalModel",
                       "calculateObjectiveRep"])
-    txs = TxArrays(np.sort(rng.uniform(0.0, 1.0, n)),
+    txs = TxArrays(np.sort(rng.uniform(w, w + 1.0, n)),
                    np.full(n, 50_000, np.int64),
                    rng.integers(0, 3, n).astype(np.int32),
                    rng.integers(0, N_ACCOUNTS, n).astype(np.int32), fns)
     loop = FusedWindowLoop(client.chain, client.target)
     loop.submit(client.target, txs)
     loop.flush()
-    loop.pump(1.0)
-    loop.run_until(1.0)
+    loop.pump(w + 1.0)
+    loop.run_until(w + 1.0)
     loop.execute()
 
 
@@ -115,25 +116,35 @@ def test_one_window_counts_itself_its_kernels_and_their_bytes(
     """Over two windows: the refold keeps the committed words on the
     device, so after the first root (which uploads them once) a window
     stages only the words it touched, well under 1% of the buffer at
-    2^18 accounts, and uploads nothing."""
+    2^18 accounts, and uploads nothing.  Over six: each window's
+    ``pack.rows`` is the unconfirmed tail ``_n - _ptr`` at its pack."""
     client, state = _node(1 << 18)
     assert obs.counters()["kernel.uploads.dirty_fold"] == 1
     obs.reset()
+    chain = client.chain
+    ptr = chain._ptr                # a window packs from here to _n
     _window(client)
     c = obs.counters()
     assert c["windows"] == 1
     for op in ("batch_seal", "dirty_fold", "block_pack"):
         assert c[f"kernel.calls.{op}"] > 0, op
-    assert c["pack.rows"] == client.chain._n > 0
+    assert c["pack.rows"] == chain._n - ptr > 0
     assert c["events.moved"] >= len(client.chain.events.since(0))
     words = state._commit_caches[("flat", STATE_CHUNK_WORDS)]["words"]
-    _window(client, seed=1)
+    _window(client, seed=1, w=1)
     c2 = obs.counters()
     assert c2["windows"] == 2
     staged = (c2["kernel.h2d_bytes.dirty_fold"]
               - c["kernel.h2d_bytes.dirty_fold"])
     assert 0 < staged < 0.01 * words.nbytes
     assert "kernel.uploads.dirty_fold" not in c2
+    # a window hands the packer its unconfirmed tail, not the history
+    for w in range(2, 6):
+        before, ptr = obs.counters()["pack.rows"], chain._ptr
+        _window(client, seed=w, w=w)
+        rows = obs.counters()["pack.rows"] - before
+        assert rows == chain._n - ptr > 0
+    assert rows < chain._n // 4
 
 
 def test_numpy_mirrors_are_not_counted(monkeypatch):
